@@ -16,13 +16,13 @@ LOSS = lambda p, b: simple.loss_fn(p, b, TASK)
 EVAL = jax.jit(lambda p, b: simple.accuracy(p, b, TASK))
 
 
-def setup_cache(path: str | None = None) -> str:
+def setup_cache() -> str:
     """Enable the persistent XLA compilation cache for this bench
-    process (``$JAX_COMPILATION_CACHE_DIR`` or the user default). CI
-    shares one directory across bench steps so every step after the
+    process (``$JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``).
+    CI shares one directory across bench steps so every step after the
     first starts warm; returns the directory used."""
     from repro.utils.cache import enable_compilation_cache
-    return enable_compilation_cache(path)
+    return enable_compilation_cache()
 
 
 def to_dev(clients, tests):

@@ -43,7 +43,7 @@ def run():
     f = jax.jit(lambda *a: ref.prox_update_ref(*a, 0.1, 0.05))
     us = _time(f, t, o, gt, go)
     got = prox_update_flat(t[:4096], o[:4096], gt[:4096], go[:4096], 0.1, 0.05,
-                           block=1024, interpret=True)
+                           block_rows=16, interpret=True)
     want = ref.prox_update_ref(t[:4096], o[:4096], gt[:4096], go[:4096], 0.1, 0.05)
     ok = np.allclose(np.asarray(got[0]), np.asarray(want[0]), atol=1e-5)
     rows.append(("kernel_prox_1.6M", us, f"allclose={ok}"))

@@ -69,6 +69,7 @@ class CompileLog:
     budget: Optional[int] = None
     count: int = 0
     cache_hits: int = 0     # persistent-compilation-cache serves (no XLA run)
+    seconds: float = 0.0    # summed backend-compile durations
     names: List[str] = dataclasses.field(default_factory=list)
 
     def describe(self) -> str:
@@ -93,39 +94,6 @@ class _LogHandler(logging.Handler):
             self._log.names.append(m.group(1))
 
 
-def _unregister_duration_listener(cb) -> None:
-    # jax's public monitoring API (0.4.x) registers but never exposes
-    # removal; use the private hook with a manual fallback so stacked
-    # budgets don't double count
-    mon = jax.monitoring
-    try:
-        from jax._src import monitoring as _m
-        _m._unregister_event_duration_listener_by_callback(cb)
-        return
-    except Exception:
-        pass
-    try:  # pragma: no cover - fallback for layout changes
-        mon._event_duration_secs_listeners.remove(cb)
-    except Exception:
-        pass
-
-
-def _unregister_event_listener(cb) -> None:
-    # same story for the plain (no-duration) event listeners, which
-    # carry the persistent-cache hit counter
-    mon = jax.monitoring
-    try:
-        from jax._src import monitoring as _m
-        _m._unregister_event_listener_by_callback(cb)
-        return
-    except Exception:
-        pass
-    try:  # pragma: no cover - fallback for layout changes
-        mon._event_listeners.remove(cb)
-    except Exception:
-        pass
-
-
 @contextlib.contextmanager
 def compile_budget(budget: Optional[int] = None, *,
                    log_names: bool = False) -> Iterator[CompileLog]:
@@ -142,6 +110,7 @@ def compile_budget(budget: Optional[int] = None, *,
     def _on_event(event: str, duration: float, **kw) -> None:
         if event == _COMPILE_EVENT:
             log.count += 1
+            log.seconds += duration
 
     def _on_hit(event: str, **kw) -> None:
         if event == _CACHE_HIT_EVENT:
@@ -160,8 +129,8 @@ def compile_budget(budget: Optional[int] = None, *,
     try:
         yield log
     finally:
-        _unregister_duration_listener(_on_event)
-        _unregister_event_listener(_on_hit)
+        jax.monitoring.unregister_event_duration_listener(_on_event)
+        jax.monitoring.unregister_event_listener(_on_hit)
         if handler is not None:
             logger.removeHandler(handler)
             jax.config.update("jax_log_compiles", prev_log_compiles)

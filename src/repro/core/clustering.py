@@ -29,7 +29,7 @@ from repro.kernels import ops
 
 class UnionFind:
     """Host union-find over client ids (path-halving find, smaller-root-
-    wins union — the semantics the device pointer-halving kernel
+    wins union — the semantics the device pointer-halving resolve
     mirrors, see ``kernels.ops.resolve_roots``)."""
 
     def __init__(self):

@@ -170,10 +170,12 @@ def _jit_observe():
     return jax.jit(run)
 
 
-def merge_round_impl(state: DeviceClusterState, tau: float, k_max: int):
+def merge_round_impl(state: DeviceClusterState, tau: float, k_max: int,
+                     mesh=None):
     """Traceable body of one fused merge pass:
     ``(state, tau, static k_max) -> (state', roots (k_max,), new_roots
-    (k_max,), counts (k_max,))``.
+    (k_max,), counts (k_max,))``. ``mesh`` is the client mesh a calling
+    program is partitioned over (see ``kernels.ops.merge_pairs``).
 
     One device program for Algorithm 1 lines 10-13: means → live-root
     compaction → fused masked-cosine-τ candidates → components →
@@ -205,7 +207,7 @@ def merge_round_impl(state: DeviceClusterState, tau: float, k_max: int):
         [means, jnp.zeros((1, means.shape[1]), means.dtype)])
     counts_c = jnp.take(jnp.concatenate([counts, jnp.zeros(1)]), rows)
     adj = ops.merge_pairs(jnp.take(means_ext, rows, axis=0),
-                          counts_c > 0, tau)
+                          counts_c > 0, tau, mesh=mesh)
     # steady-state rounds have no candidate pair at all — skip the
     # O(log K̃) propagation entirely instead of running it on an
     # empty graph (the common case once the partition settles)

@@ -357,20 +357,58 @@ def scan_program(state: ServerState, rounds: int, unavailable=frozenset()):
     cache_key = (f"scan:{state.strategy}:{rounds}:{m}:"
                  f"{hash((str(structure), shapes, statics))}")
 
+    # donate the carry off-CPU: the prior state's model/bank/partition
+    # buffers roll straight into the scan's carry allocation, so a
+    # steady-state span allocates nothing net. Callers already treat
+    # the input state as consumed (run_rounds returns the successor
+    # state and the parity battery rebinds it); on CPU the input state
+    # stays readable, so donation is skipped there.
+    donate = jax.default_backend() != "cpu"
+    if donate:
+        carry0 = _unaliased(carry0, (consts, ctx.init_params))
+
     def build():
         def scan_fn(c0, cs):
             return jax.lax.scan(lambda c, _: step(c, cs), c0, None,
                                 length=rounds)
-        # donate the carry off-CPU: the prior state's model/bank/partition
-        # buffers roll straight into the scan's carry allocation, so a
-        # steady-state span allocates nothing net. Callers already treat
-        # the input state as consumed (run_rounds returns the successor
-        # state and the parity battery rebinds it); CPU ignores donation,
-        # so skip it there to keep compiles warning-free.
-        donate = () if jax.default_backend() == "cpu" else (0,)
-        return jax.jit(scan_fn, donate_argnums=donate)
+        return jax.jit(scan_fn, donate_argnums=(0,) if donate else ())
 
     return ctx.jit(cache_key, build), carry0, consts, finalize
+
+
+def _buffers(x) -> set:
+    """Device buffer addresses behind a jax array (one per shard)."""
+    import jax
+
+    if not isinstance(x, jax.Array):
+        return set()
+    return {s.data.unsafe_buffer_pointer() for s in x.addressable_shards}
+
+
+def _unaliased(carry, held):
+    """``carry`` with a private copy of every leaf whose device buffer is
+    also behind ``held`` or an earlier carry leaf. A fresh state's ω IS
+    ``ctx.init_params`` — the lazy cluster-model default, the Ψ anchor in
+    fp32 and a scan const — and a call that donates a buffer while also
+    reading it is refused (``f(donate(a), a)``), besides deleting the
+    context's copy. Only aliased leaves are copied, so a warm carry keeps
+    donating in place."""
+    import jax
+    import jax.numpy as jnp
+
+    taken = set()
+    for x in jax.tree.leaves(held):
+        taken |= _buffers(x)
+
+    def own(x):
+        bufs = _buffers(x)
+        if bufs & taken:
+            x = jnp.copy(x)
+            bufs = _buffers(x)
+        taken.update(bufs)
+        return x
+
+    return jax.tree.map(own, carry)
 
 
 def scan_history(ys, rounds: int):

@@ -653,7 +653,7 @@ class StoCFLStrategy(Strategy):
             run_merge = new_any | ~settled
 
             def do_merge(d):
-                return devclust.merge_round_impl(d, tau, k_bound)
+                return devclust.merge_round_impl(d, tau, k_bound, mesh)
 
             def skip_merge(d):
                 pad = jnp.full((k_bound,), cap, jnp.int32)

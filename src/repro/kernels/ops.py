@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels, with backend selection.
 
-On this container (CPU) the Pallas TPU kernels execute in interpret mode;
-on a real TPU the same call sites compile to Mosaic. ``backend="jnp"``
-routes to the pure-jnp oracle — the default inside big jitted graphs where
-interpret-mode would be slow.
+``backend="auto"`` picks by platform: the Pallas kernel compiled by
+Mosaic on TPU, the pure-jnp oracle everywhere else. ``backend="jnp"``
+forces the oracle. Nothing here runs a kernel in interpret mode or
+falls back to the oracle after a TPU compile error: the kernel tests
+call the kernels in interpret mode themselves, and
+``tests/test_tpu_compile.py`` compiles them for a described v5e.
 """
 from __future__ import annotations
 
@@ -11,14 +13,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.cosine_sim import cosine_sim as _cosine_pallas
 from repro.kernels.cosine_sim import merge_candidates as _candidates_pallas
 from repro.kernels.prox_update import prox_update_flat as _prox_pallas
 from repro.kernels.ssm_scan import ssm_scan as _ssm_pallas
-from repro.utils import trees
 
 
 def _on_tpu() -> bool:
@@ -29,49 +30,41 @@ def pairwise_cosine(x, backend: str = "auto"):
     """(N, D) representation matrix -> (N, N) cosine similarity."""
     if backend == "jnp" or (backend == "auto" and not _on_tpu()):
         return ref.cosine_sim_ref(x)
-    return _cosine_pallas(x, interpret=not _on_tpu())
+    return _cosine_pallas(x)
 
 
-def merge_pairs(means, live, tau: float, backend: str = "auto"):
+def merge_pairs(means, live, tau: float, backend: str = "auto", mesh=None):
     """(K, D) cluster means + (K,) live mask -> (K, K) fp32 0/1 adjacency
     of mergeable pairs (cos ≥ τ, both live, diagonal off) — Algorithm 1
-    line 10 as one fused device op (``cosine_sim.merge_candidates``)."""
+    line 10 as one fused device op (``cosine_sim.merge_candidates``).
+
+    ``mesh``: the client mesh the calling program is partitioned over.
+    GSPMD cannot partition a Mosaic kernel, so there the kernel runs
+    under ``shard_map`` on every device over the replicated inputs."""
     if backend == "jnp" or (backend == "auto" and not _on_tpu()):
         return ref.merge_candidates_ref(means, live, tau)
-    return _candidates_pallas(means, live, tau=float(tau),
-                              interpret=not _on_tpu())
+    kernel = functools.partial(_candidates_pallas, tau=float(tau))
+    if mesh is not None:
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=P(), check_vma=False)
+    return kernel(means, live)
 
 
-# --------------------------------------------------------------- union-find
-def _halving_kernel(steps, parent_ref, out_ref):
-    out_ref[...] = jax.lax.fori_loop(
-        0, steps, lambda _, p: jnp.take(p, p), parent_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _resolve_pallas(parent, interpret: bool = False):
-    n = parent.shape[0]
-    steps = max(int(n).bit_length(), 1)
-    return pl.pallas_call(
-        functools.partial(_halving_kernel, steps),
-        out_shape=jax.ShapeDtypeStruct((n,), parent.dtype),
-        interpret=interpret,
-    )(parent)
-
-
-def resolve_roots(parent, backend: str = "auto"):
+def resolve_roots(parent):
     """(N,) union-find parent array (``parent[i] == i`` at roots) ->
     (N,) fully-resolved roots.
 
     Iterated pointer halving ``p <- p[p]``: every find-path halves per
-    step, so ⌈log2 N⌉+1 in-VMEM gathers resolve ANY forest — the device
+    step, so ⌈log2 N⌉+1 gathers resolve ANY forest — the device
     replacement for the numpy ``UnionFind.find`` Python loop. The whole
     array resolves as one vectorized op per step, and the step count
     depends only on the (static, pow2-padded) capacity, so the op jits
-    into the clustering round with no data-dependent control flow."""
-    if backend == "jnp" or (backend == "auto" and not _on_tpu()):
-        return ref.resolve_roots_ref(parent)
-    return _resolve_pallas(parent, interpret=not _on_tpu())
+    into the clustering round with no data-dependent control flow.
+
+    The same XLA gather runs on every platform: the pointer chase needs
+    a 1-D dynamic gather, which Mosaic (Pallas on TPU) does not lower,
+    so there is no kernel for it."""
+    return ref.resolve_roots_ref(parent)
 
 
 def prox_update_tree(theta, omega, g_theta, g_omega, eta, lam, backend: str = "auto"):
@@ -87,7 +80,6 @@ def prox_update_tree(theta, omega, g_theta, g_omega, eta, lam, backend: str = "a
             omega, g_omega)
         return th, om
 
-    interp = not _on_tpu()
     th_leaves, treedef = jax.tree.flatten(theta)
     om_leaves = treedef.flatten_up_to(omega)
     gt_leaves = treedef.flatten_up_to(g_theta)
@@ -95,7 +87,7 @@ def prox_update_tree(theta, omega, g_theta, g_omega, eta, lam, backend: str = "a
     new_th, new_om = [], []
     for t, o, gt, go in zip(th_leaves, om_leaves, gt_leaves, go_leaves):
         tn, on = _prox_pallas(t.ravel(), o.ravel(), gt.ravel(), go.ravel(),
-                              eta, lam, interpret=interp)
+                              eta, lam)
         new_th.append(tn.reshape(t.shape).astype(t.dtype))
         new_om.append(on.reshape(o.shape).astype(o.dtype))
     return jax.tree.unflatten(treedef, new_th), jax.tree.unflatten(treedef, new_om)
@@ -117,12 +109,11 @@ def prox_update_flat(theta, omega, g_theta, g_omega, eta, lam,
               ).astype(theta.dtype)
         om = (om32 - eta * g_omega.astype(jnp.float32)).astype(omega.dtype)
         return th, om
-    return _prox_pallas(theta, omega, g_theta, g_omega, eta, lam,
-                        interpret=not _on_tpu(), **kw)
+    return _prox_pallas(theta, omega, g_theta, g_omega, eta, lam, **kw)
 
 
 def ssm_scan(dA, dBx, C, backend: str = "auto", **kw):
     """Fused selective scan. See kernels/ssm_scan.py."""
     if backend == "jnp" or (backend == "auto" and not _on_tpu()):
         return ref.ssm_scan_ref(dA, dBx, C)
-    return _ssm_pallas(dA, dBx, C, interpret=not _on_tpu(), **kw)
+    return _ssm_pallas(dA, dBx, C, **kw)

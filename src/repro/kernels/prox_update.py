@@ -4,18 +4,25 @@ Algorithm 1 lines 21-22, fused into one HBM pass:
     θ' = θ − η (g_θ + λ (θ − ω))
     ω' = ω − η g_ω
 Unfused this reads/writes 4+2 arrays in ~7 passes; fused it streams each
-operand exactly once (memory-bound, VPU elementwise). 1-D tiling over the
-flattened parameter vector; block 64k floats (256 KiB fp32) per operand
-keeps the 6-operand working set ≈1.5 MiB — comfortably inside VMEM.
+operand exactly once (memory-bound, VPU elementwise).
 
-Block-aligned vectors (the common case for the flatten-once adapter in
-``core.bilevel``, which can pick its own block) pass straight through:
-no padding copy, and θ/ω alias their outputs so the update happens in
-the operands' own buffers. Misaligned sizes pay one ``jnp.pad`` per
-operand (an append, not the old full-size zero-init + scatter-copy).
-Inputs are donated off-CPU — callers must treat the four arrays as
-consumed, which every call site of the fused path already does (grads
-are per-step temporaries, θ/ω are immediately rebound).
+Tiling: the flat parameter vector is viewed as a 2-D ``(rows, 128)``
+slab (one vreg lane width per row) and the grid walks blocks of
+``block_rows`` rows; the default 512 rows is 64k floats (256 KiB fp32)
+per operand, so the 6-operand working set stays ≈1.5 MiB — comfortably
+inside VMEM. The 2-D view is what lets the kernel run under the cohort
+``vmap``: batching prepends an axis to the block, and Mosaic accepts a
+block only when its last two dims are (multiple of 8, multiple of 128)
+or span the array — a 1-D ``(block,)`` block becomes an illegal
+``(1, block)``, a 2-D ``(block_rows, 128)`` one stays legal.
+
+Vectors whose length is a whole number of blocks pass straight through:
+the 2-D view is a free reshape, no padding copy, and θ/ω alias their
+outputs so the update happens in the operands' own buffers. Other sizes
+pay one ``jnp.pad`` per operand. Inputs are donated off-CPU — callers
+must treat the four arrays as consumed, which every call site of the
+fused path already does (grads are per-step temporaries, θ/ω are
+immediately rebound).
 """
 from __future__ import annotations
 
@@ -25,11 +32,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+# row granule: 16 sublanes fit one packed bf16 vreg (8 for f32), so a
+# block of a multiple of 16 rows is legal for both compute dtypes
+_ROW_GRANULE = 16
+
 
 def _prox_kernel(theta_ref, omega_ref, gt_ref, go_ref, eta_ref, lam_ref,
                  theta_out_ref, omega_out_ref):
-    eta = eta_ref[0]
-    lam = lam_ref[0]
+    eta = eta_ref[0, 0]
+    lam = lam_ref[0, 0]
     th = theta_ref[...].astype(jnp.float32)
     om = omega_ref[...].astype(jnp.float32)
     theta_out_ref[...] = (th - eta * (gt_ref[...].astype(jnp.float32) + lam * (th - om))
@@ -38,37 +50,34 @@ def _prox_kernel(theta_ref, omega_ref, gt_ref, go_ref, eta_ref, lam_ref,
 
 
 def _prox_call(theta, omega, g_theta, g_omega, eta, lam, *,
-               block: int, interpret: bool):
+               block_rows: int, interpret: bool):
     """Traced body shared by the donating and non-donating entry jits."""
     n = theta.shape[0]
-    n_pad = -(-n // block) * block
+    rows = -(-n // LANES)
+    rows = -(-rows // _ROW_GRANULE) * _ROW_GRANULE
+    br = min(-(-block_rows // _ROW_GRANULE) * _ROW_GRANULE, rows)
+    rows = -(-rows // br) * br
+    n_pad = rows * LANES
     if n_pad != n:
         # misaligned tail: one append-pad per operand (pad values are
         # computed but sliced off below — they never feed anything)
         theta, omega, g_theta, g_omega = (
             jnp.pad(a, (0, n_pad - n))
             for a in (theta, omega, g_theta, g_omega))
-    eta_v = jnp.full((1,), eta, jnp.float32)
-    lam_v = jnp.full((1,), lam, jnp.float32)
+    slabs = [a.reshape(rows, LANES) for a in (theta, omega, g_theta, g_omega)]
+    eta_v = jnp.full((1, 1), eta, jnp.float32)
+    lam_v = jnp.full((1, 1), lam, jnp.float32)
 
+    block = pl.BlockSpec((br, LANES), lambda i: (i, 0))
+    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
     outs = pl.pallas_call(
         _prox_kernel,
-        grid=(n_pad // block,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
+        grid=(rows // br,),
+        in_specs=[block, block, block, block, scalar, scalar],
+        out_specs=[block, block],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), theta.dtype),
-            jax.ShapeDtypeStruct((n_pad,), omega.dtype),
+            jax.ShapeDtypeStruct((rows, LANES), theta.dtype),
+            jax.ShapeDtypeStruct((rows, LANES), omega.dtype),
         ],
         # θ/ω update in place: with the jit-level donation below, the
         # aligned path writes back into the operands' own HBM buffers
@@ -76,31 +85,34 @@ def _prox_call(theta, omega, g_theta, g_omega, eta, lam, *,
         # copy semantics — still correct, just not in-place)
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(theta, omega, g_theta, g_omega, eta_v, lam_v)
+    )(*slabs, eta_v, lam_v)
+    th_out, om_out = (o.reshape(n_pad) for o in outs)
     if n_pad != n:
-        return outs[0][:n], outs[1][:n]
-    return outs[0], outs[1]
+        return th_out[:n], om_out[:n]
+    return th_out, om_out
 
 
-_prox_jit = functools.partial(jax.jit, static_argnames=("block", "interpret"))
+_prox_jit = functools.partial(jax.jit,
+                              static_argnames=("block_rows", "interpret"))
 _prox_plain = _prox_jit(_prox_call)
 _prox_donating = _prox_jit(_prox_call, donate_argnums=(0, 1, 2, 3))
 
 
 def prox_update_flat(theta, omega, g_theta, g_omega, eta, lam, *,
-                     block: int = 65536, interpret: bool = False,
+                     block_rows: int = 512, interpret: bool = False,
                      donate=None):
     """All four arrays 1-D of equal length; returns (theta', omega').
 
+    ``block_rows`` is the grid block height in 128-lane rows (rounded up
+    to a multiple of 16, capped at the vector's own height).
     ``donate=None`` resolves at CALL time: off-CPU the four operands are
     donated (their buffers are recycled into the outputs — the caller
-    must not reuse them); on CPU jax ignores donation, so the plain jit
-    is used to keep compiles warning-free. Pass an explicit bool to
-    override."""
+    must not reuse them); on CPU the plain jit is used so inputs stay
+    readable. Pass an explicit bool to override."""
     if theta.shape[0] == 0:
         return theta, omega
     if donate is None:
         donate = jax.default_backend() != "cpu"
     fn = _prox_donating if donate else _prox_plain
     return fn(theta, omega, g_theta, g_omega, eta, lam,
-              block=block, interpret=interpret)
+              block_rows=block_rows, interpret=interpret)

@@ -29,6 +29,7 @@ from repro.configs import get_config
 from repro.core.extractor import llm_leaf_filter
 from repro.data import synthetic_lm_batch
 from repro.models import build
+from repro.utils.cache import enable_compilation_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -103,6 +104,7 @@ def make_requests(cfg, n: int, prompt_len: int, gen: int, clusters: int,
 
 def main():
     args = build_parser().parse_args()
+    enable_compilation_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build(cfg)
     st = build_server_state(cfg, model, args.clusters, args.tau, args.seed)

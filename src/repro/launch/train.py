@@ -42,6 +42,7 @@ from repro.data import make_federation, synthetic_lm_batch
 from repro.models import build, simple
 from repro.configs import get_config
 from repro.launch.mesh import make_client_mesh
+from repro.utils.cache import enable_compilation_cache
 
 
 def _engine_cfg(args) -> engine.EngineConfig:
@@ -235,12 +236,6 @@ def main():
                     help="compute dtype for client params/grads/batches; "
                          "Ψ-embeddings, cluster means and the Eq. 2 "
                          "objective always stay float32")
-    ap.add_argument("--compile-cache", nargs="?", const="auto", default=None,
-                    metavar="DIR",
-                    help="persist compiled XLA executables to DIR (bare "
-                         "flag: $JAX_COMPILATION_CACHE_DIR or "
-                         "~/.cache/repro-jax-cache) so warm restarts skip "
-                         "the compile tax")
     ap.add_argument("--async", dest="async_mode", action="store_true",
                     help="async buffered aggregation (engine."
                          "run_round_async): delayed client deltas land in "
@@ -283,11 +278,7 @@ def main():
         raise SystemExit("--async is host-orchestrated (the delta buffer "
                          "bookkeeping lives on the host) and cannot be "
                          "fused with --scan-rounds")
-    if args.compile_cache is not None:
-        from repro.utils.cache import enable_compilation_cache
-        path = enable_compilation_cache(
-            None if args.compile_cache == "auto" else args.compile_cache)
-        print(f"compilation cache: {path}")
+    print(f"compilation cache: {enable_compilation_cache()}")
     if args.arch:
         run_llm(args)
     else:

@@ -11,6 +11,8 @@ points: donation resolves at call/build time and is OFF on CPU, so
 donated-in-name inputs stay readable and no hidden host↔device copies
 appear (``no_transfer`` guard).
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,29 +20,39 @@ import pytest
 
 from repro.analysis import sanitize
 from repro.kernels.prox_update import prox_update_flat
-from repro.utils.cache import enable_compilation_cache
+from repro.utils.cache import (CHECKOUT_CACHE_DIR, cache_dir,
+                               enable_compilation_cache)
 
 
-def test_compilation_cache_serves_after_clear(tmp_path):
+def test_compilation_cache_serves_after_clear(tmp_path, monkeypatch):
+    """With ``$JAX_COMPILATION_CACHE_DIR`` set, the cache lands there and
+    nowhere else, and a recompile after ``clear_caches`` is served."""
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_time = jax.config.jax_persistent_cache_min_compile_time_secs
     prev_size = jax.config.jax_persistent_cache_min_entry_size_bytes
+    checkout = os.listdir(CHECKOUT_CACHE_DIR) if os.path.isdir(
+        CHECKOUT_CACHE_DIR) else []
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     try:
-        used = enable_compilation_cache(str(tmp_path))
+        used = enable_compilation_cache()
         assert used == str(tmp_path)
 
         @jax.jit
         def f(x):
-            return jnp.tanh(x) * 3.0 + jnp.cos(x)
+            return jnp.tanh(x) * 3.0 + jnp.cos(x) - 0.25
 
         x = jnp.arange(128, dtype=jnp.float32)
         want = np.asarray(f(x))                   # cold: compiles + writes
+        assert os.listdir(tmp_path), "no cache entry in the env directory"
         jax.clear_caches()
         with sanitize.compile_budget() as log:
             got = np.asarray(f(x))                # warm: served from disk
         np.testing.assert_array_equal(want, got)
         assert log.cache_hits >= 1, "recompile was not served from the cache"
         assert log.count >= log.cache_hits
+        now = os.listdir(CHECKOUT_CACHE_DIR) if os.path.isdir(
+            CHECKOUT_CACHE_DIR) else []
+        assert now == checkout, "an entry went to the checkout cache too"
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
@@ -53,6 +65,18 @@ def test_compilation_cache_serves_after_clear(tmp_path):
         cc.reset_cache()
 
 
+def test_cache_dir_defaults_to_checkout(monkeypatch):
+    """Unset, the cache goes to ``<checkout>/.jax_cache``, which git
+    ignores; set, the variable wins."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert cache_dir() == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cache_dir() == "/elsewhere"
+
+
 def test_prox_donation_contract_on_cpu():
     # the donate=None default resolves to NON-donating on CPU: inputs
     # stay readable and no implicit host transfer sneaks past the guard
@@ -61,7 +85,7 @@ def test_prox_donation_contract_on_cpu():
     eta, lam = jnp.float32(0.1), jnp.float32(0.05)   # device scalars
     with sanitize.no_transfer():
         t2, o2 = prox_update_flat(th, om, gt, go, eta, lam,
-                                  block=32, interpret=True)
+                                  block_rows=16, interpret=True)
         t2.block_until_ready()
     f32 = np.float32
     exp_t = f32(1.0) - f32(0.1) * (f32(0.5) + f32(0.05) * (f32(1.0) - f32(0.0)))
@@ -75,7 +99,7 @@ def test_prox_donation_contract_on_cpu():
     # them) — this is why the call-time default matters, and why every
     # fused call site rebinds θ/ω immediately instead of reusing them
     t3, _ = prox_update_flat(th, om, gt, go, eta, lam,
-                             block=32, interpret=True, donate=True)
+                             block_rows=16, interpret=True, donate=True)
     np.testing.assert_array_equal(np.asarray(t3), np.asarray(t2))
     with pytest.raises(RuntimeError, match="deleted"):
         np.asarray(th)
@@ -141,3 +165,47 @@ def test_donated_carry_sharding_is_scan_fixed_point():
             assert a.sharding.is_equivalent_to(b.sharding, a.ndim), \
                 f"{name}: carry sharding not a scan fixed point " \
                 f"({a.sharding} -> {b.sharding})"
+
+
+@pytest.mark.parametrize("name", ["stocfl", "fedavg", "fedprox", "ditto",
+                                  "ifca", "cfl"])
+def test_donated_scan_matches_undonated(name, monkeypatch):
+    """The accelerator path, rehearsed on the CPU (which implements
+    donation): with every donation gate on, two back-to-back
+    ``run_rounds`` spans — the second warm-resumed from the first's
+    donated carry — then ``evaluate`` run without a deleted-array or
+    donate-and-read error, and land bitwise on the undonated result. A
+    fresh state's ω is ``ctx.init_params``, which the scan also reads
+    as a const; the scan must not donate that buffer."""
+    from repro import engine
+    from repro.data import rotated
+    from repro.models import simple
+
+    task = simple.SYNTH_MLP
+    loss = lambda p, b: simple.loss_fn(p, b, task)
+    evalf = jax.jit(lambda p, b: simple.accuracy(p, b, task))
+    clients, tc, tests = rotated(n_clusters=2, n_clients=8, n_per=16, seed=0)
+    clients = [jax.tree.map(jnp.asarray, c) for c in clients]
+    tests = {k: jax.tree.map(jnp.asarray, v) for k, v in tests.items()}
+    kw = dict(local_steps=1, sample_rate=1.0 if name == "cfl" else 0.5,
+              seed=0, rng_backend="device")
+    if name == "stocfl":
+        kw["cluster_backend"] = "device"
+
+    def run():
+        st = engine.init(name, loss, simple.init(jax.random.PRNGKey(0), task),
+                         clients, engine.EngineConfig(**kw), eval_fn=evalf,
+                         arena=True)
+        st = engine.run_rounds(engine.run_rounds(st, 2), 2)
+        return st, engine.evaluate(st, tests, tc)
+
+    plain, plain_eval = run()
+    # every `default_backend() != "cpu"` gate opens; "gpu" keeps the
+    # kernels on their jnp oracles
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    donated, donated_eval = run()
+    assert donated_eval == plain_eval
+    assert donated.history == plain.history
+    for a, b in zip(jax.tree.leaves(donated.omega),
+                    jax.tree.leaves(plain.omega)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

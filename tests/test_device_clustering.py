@@ -217,10 +217,11 @@ def test_chain_topology_same_partition_and_bank_merge():
 
 
 def test_pallas_kernels_match_oracles_interpret_mode():
-    """Interpret-mode smoke for the two new Pallas kernels (the
-    hypothesis sweeps in test_kernels.py need the test extra; this
-    always runs): fused masked-cosine+τ candidates and pointer-halving
-    root resolution against their jnp oracles."""
+    """Smoke for the device-clustering kernels (the hypothesis sweeps in
+    test_kernels.py need the test extra; this always runs): the fused
+    masked-cosine+τ candidate kernel in interpret mode against its jnp
+    oracle, and pointer-halving root resolution against a plain
+    union-find ``find``."""
     from repro.kernels import ops, ref
     from repro.kernels.cosine_sim import merge_candidates
 
@@ -235,9 +236,11 @@ def test_pallas_kernels_match_oracles_interpret_mode():
     parent = np.arange(37, dtype=np.int32)
     for i in rng.permutation(37)[:20]:
         parent[i] = rng.integers(0, i + 1)
-    got = ops._resolve_pallas(jnp.asarray(parent), interpret=True)
-    want = ref.resolve_roots_ref(jnp.asarray(parent))
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    got = ops.resolve_roots(jnp.asarray(parent))
+    uf = UnionFind()
+    uf.parent = {i: int(p) for i, p in enumerate(parent)}
+    want = [uf.find(i) for i in range(len(parent))]
+    assert np.array_equal(np.asarray(got), want)
 
 
 def test_nearest_and_infer_parity():

@@ -31,7 +31,8 @@ TASK = simple.SYNTH_MLP
 LOSS = lambda p, b: simple.loss_fn(p, b, TASK)
 
 ALL = ["stocfl", "fedavg", "fedprox", "ditto", "ifca", "cfl"]
-BLOCK = 8
+BLOCK_ROWS = 16
+BLOCK = BLOCK_ROWS * 128        # floats per kernel block
 
 
 def _vecs(n, seed=0):
@@ -45,7 +46,7 @@ def test_prox_kernel_matches_oracle_at_padding_boundaries(n):
     th, om, gt, go = _vecs(n)
     eta, lam = 0.1, 0.05
     want = ops.prox_update_flat(th, om, gt, go, eta, lam, backend="jnp")
-    got = prox_pallas(th, om, gt, go, eta, lam, block=BLOCK,
+    got = prox_pallas(th, om, gt, go, eta, lam, block_rows=BLOCK_ROWS,
                       interpret=True, donate=False)
     for w, g in zip(want, got):
         assert g.shape == (n,)
@@ -58,7 +59,7 @@ def test_prox_kernel_matches_oracle_at_padding_boundaries(n):
 
 def test_prox_kernel_empty_is_identity():
     th, om, gt, go = _vecs(0)
-    t2, o2 = prox_pallas(th, om, gt, go, 0.1, 0.05, block=BLOCK,
+    t2, o2 = prox_pallas(th, om, gt, go, 0.1, 0.05, block_rows=BLOCK_ROWS,
                          interpret=True, donate=False)
     assert t2.shape == (0,) and o2.shape == (0,)
 

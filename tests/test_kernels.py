@@ -1,5 +1,6 @@
 """Per-kernel allclose vs the ref.py oracles, with hypothesis shape/dtype
-sweeps, executed in Pallas interpret mode on CPU (TPU is the target)."""
+sweeps, executed in Pallas interpret mode on CPU (TPU is the target;
+tests/test_tpu_compile.py compiles the same kernels for a v5e)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +8,7 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests need the test extra
 from hypothesis import given, settings, strategies as st
 
+from repro.core.clustering import UnionFind
 from repro.kernels import ref
 from repro.kernels.cosine_sim import cosine_sim
 from repro.kernels.prox_update import prox_update_flat
@@ -47,7 +49,7 @@ def test_cosine_sim_zero_row_safe():
 def test_prox_update_sweep(n, eta, lam):
     ks = jax.random.split(jax.random.PRNGKey(n), 4)
     t, o, gt, go = (jax.random.normal(k, (n,)) for k in ks)
-    got_t, got_o = prox_update_flat(t, o, gt, go, eta, lam, block=256, interpret=True)
+    got_t, got_o = prox_update_flat(t, o, gt, go, eta, lam, block_rows=16, interpret=True)
     want_t, want_o = ref.prox_update_ref(t, o, gt, go, eta, lam)
     np.testing.assert_allclose(np.asarray(got_t), np.asarray(want_t), atol=1e-5)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), atol=1e-5)
@@ -57,7 +59,7 @@ def test_prox_update_lambda_zero_is_sgd():
     """λ=0 degenerates to two independent SGD steps (paper §3.4)."""
     ks = jax.random.split(KEY, 4)
     t, o, gt, go = (jax.random.normal(k, (300,)) for k in ks)
-    got_t, got_o = prox_update_flat(t, o, gt, go, 0.1, 0.0, block=128, interpret=True)
+    got_t, got_o = prox_update_flat(t, o, gt, go, 0.1, 0.0, block_rows=16, interpret=True)
     np.testing.assert_allclose(np.asarray(got_t), np.asarray(t - 0.1 * gt), atol=1e-6)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(o - 0.1 * go), atol=1e-6)
 
@@ -67,7 +69,7 @@ def test_prox_update_pull_toward_omega():
     t = jnp.ones((100,)) * 5.0
     o = jnp.zeros((100,))
     z = jnp.zeros((100,))
-    got_t, _ = prox_update_flat(t, o, z, z, 0.1, 1.0, block=64, interpret=True)
+    got_t, _ = prox_update_flat(t, o, z, z, 0.1, 1.0, block_rows=16, interpret=True)
     assert float(jnp.max(jnp.abs(got_t))) < 5.0
 
 
@@ -133,12 +135,13 @@ def test_cosine_sim_padded_sweep(n_real, n_pad_extra):
 
 def test_prox_update_ragged_tail_matches_ref():
     """Flat param lengths from ragged-arena models never align to the
-    block; the kernel's internal zero-pad must not leak into the tail."""
-    for n in [1, 63, 64, 65, 255, 257, 1000]:
+    block (16 rows × 128 lanes = 2048 floats); the kernel's internal
+    zero-pad must not leak into the tail."""
+    for n in [1, 127, 128, 129, 2047, 2049, 5000]:
         ks = jax.random.split(jax.random.PRNGKey(n), 4)
         t, o, gt, go = (jax.random.normal(k, (n,)) for k in ks)
         got_t, got_o = prox_update_flat(t, o, gt, go, 0.05, 0.3,
-                                        block=64, interpret=True)
+                                        block_rows=16, interpret=True)
         want_t, want_o = ref.prox_update_ref(t, o, gt, go, 0.05, 0.3)
         assert got_t.shape == (n,) and got_o.shape == (n,)
         np.testing.assert_allclose(np.asarray(got_t), np.asarray(want_t),
@@ -158,7 +161,7 @@ def test_prox_update_masked_region_identity():
     gt = jax.random.normal(jax.random.fold_in(KEY, 2), (n,)) * mask
     go = jax.random.normal(jax.random.fold_in(KEY, 3), (n,)) * mask
     got_t, got_o = prox_update_flat(t, o, gt, go, 0.1, 0.5,
-                                    block=64, interpret=True)
+                                    block_rows=16, interpret=True)
     pad = np.asarray(mask) == 0.0
     np.testing.assert_allclose(np.asarray(got_o)[pad],
                                np.asarray(o)[pad], atol=1e-6)
@@ -220,27 +223,34 @@ def test_merge_candidates_diagonal_and_dead_rows():
 
 
 # --------------------------------------------- resolve_roots (pointer halving)
+def _numpy_find_roots(parent):
+    """The host union-find ``find`` of every node — the reference for
+    ``ops.resolve_roots``."""
+    uf = UnionFind()
+    uf.parent = {i: int(p) for i, p in enumerate(parent)}
+    return np.array([uf.find(i) for i in range(len(parent))], np.int32)
+
+
 @settings(max_examples=15, deadline=None)
 @given(n=st.integers(2, 200), seed=st.integers(0, 1000))
 def test_resolve_roots_pallas_sweep(n, seed):
-    """Pointer-halving kernel resolves ANY random forest to the same
-    roots as the jnp oracle (interpret mode)."""
+    """Pointer halving resolves ANY random forest to the roots a plain
+    union-find ``find`` reaches."""
     rng = np.random.default_rng(seed)
     parent = np.arange(n, dtype=np.int32)
     for i in rng.permutation(n)[: n // 2]:      # random valid forest:
         parent[i] = rng.integers(0, i + 1)      # parent id <= own id
-    got = np.asarray(ops._resolve_pallas(jnp.asarray(parent),
-                                         interpret=True))
-    want = np.asarray(ref.resolve_roots_ref(jnp.asarray(parent)))
-    np.testing.assert_array_equal(got, want)
-    # and the oracle itself is a fixed point: every root self-parents
-    np.testing.assert_array_equal(want, np.asarray(want)[want])
+    got = np.asarray(ops.resolve_roots(jnp.asarray(parent)))
+    np.testing.assert_array_equal(got, _numpy_find_roots(parent))
+    # and the result is a fixed point: every root self-parents
+    np.testing.assert_array_equal(got, got[got])
 
 
 def test_resolve_roots_worst_case_chain():
-    """A maximal-depth chain still resolves in the kernel's static
-    ⌈log2 N⌉+1 steps."""
-    n = 129
-    parent = jnp.asarray(np.maximum(np.arange(n, dtype=np.int32) - 1, 0))
-    got = np.asarray(ops._resolve_pallas(parent, interpret=True))
+    """A maximal-depth chain still resolves in the static ⌈log2 N⌉+1
+    halving steps."""
+    n = 4096
+    parent = np.maximum(np.arange(n, dtype=np.int32) - 1, 0)
+    got = np.asarray(ops.resolve_roots(jnp.asarray(parent)))
+    np.testing.assert_array_equal(got, _numpy_find_roots(parent))
     np.testing.assert_array_equal(got, np.zeros(n, np.int32))
