@@ -285,10 +285,16 @@ def run_rounds(state: ServerState, rounds: int,
 
     Returns the state after ``rounds`` rounds.
     """
+    from jax.profiler import TraceAnnotation
+
     rounds = int(rounds)
     if rounds <= 0:
         return state
-    program = scan_program(state, rounds, unavailable)
+    # host spans of the call, tied together by their ``round`` argument
+    # (docs/ARCHITECTURE.md, "Tracing a run")
+    ids = dict(round=state.round, rounds=rounds)
+    with TraceAnnotation("repro.scan.prepare", **ids):
+        program = scan_program(state, rounds, unavailable)
     if program is None:
         # all departed/unavailable: the eager path raises per round; the
         # scanned path records the span as skipped no-op rounds
@@ -296,8 +302,10 @@ def run_rounds(state: ServerState, rounds: int,
         return state.replace(round=state.round + rounds,
                              history=state.history + recs)
     fn, carry0, consts, finalize = program
-    carry, ys = fn(carry0, consts)
-    return finalize(state, carry, ys, int(rounds))
+    with TraceAnnotation("repro.scan.dispatch", **ids):
+        carry, ys = fn(carry0, consts)
+    with TraceAnnotation("repro.scan.finalize", **ids):
+        return finalize(state, carry, ys, rounds)
 
 
 def scan_program(state: ServerState, rounds: int, unavailable=frozenset()):

@@ -119,13 +119,14 @@ def _gather_scan(consts: dict, ids, ragged: bool, mesh=None):
     (``ClientArena.place``), the take is a cross-shard gather, and the
     gathered batch is re-constrained onto the client axes so the
     per-client training that follows partitions over the devices."""
-    idx = jnp.take(consts["rowmap"], ids)
-    batch = jax.tree.map(lambda x: jnp.take(x, idx, axis=0),
-                         consts["packed"])
-    if ragged:
-        batch = dict(batch)
-        batch["mask"] = jnp.take(consts["amask"], idx, axis=0)
-    return specs.constrain_cohort(batch, mesh)
+    with jax.named_scope("cohort_gather"):
+        idx = jnp.take(consts["rowmap"], ids)
+        batch = jax.tree.map(lambda x: jnp.take(x, idx, axis=0),
+                             consts["packed"])
+        if ragged:
+            batch = dict(batch)
+            batch["mask"] = jnp.take(consts["amask"], idx, axis=0)
+        return specs.constrain_cohort(batch, mesh)
 
 
 @functools.lru_cache(maxsize=512)
@@ -621,10 +622,11 @@ class StoCFLStrategy(Strategy):
         def step(carry, cs):
             key, omega, dcs, rows, has, obj, settled = carry
             ids_arr = jnp.arange(cap, dtype=jnp.int32)
-            key, ids = cohort_sampler.draw(key, cs["pool"], m)
+            # each layer of the round runs under one named scope: the
+            # ops' metadata, and so a profiler trace, carries the name
+            with jax.named_scope("cohort_gather"):
+                key, ids = cohort_sampler.draw(key, cs["pool"], m)
             batches = _gather_scan(cs, ids, ragged, mesh)
-            new = ~jnp.take(dcs.live, ids)
-            new_any = jnp.any(new)
 
             def observe(d):
                 # Ψ per cohort member, one client at a time (lax.map
@@ -640,7 +642,10 @@ class StoCFLStrategy(Strategy):
                     rep=d.rep.at[idx].set(reps.astype(d.rep.dtype),
                                           mode="drop"))
 
-            dcs = jax.lax.cond(new_any, observe, lambda d: d, dcs)
+            with jax.named_scope("psi_extraction"):
+                new = ~jnp.take(dcs.live, ids)
+                new_any = jnp.any(new)
+                dcs = jax.lax.cond(new_any, observe, lambda d: d, dcs)
             # settled-skip: once a merge pass runs with no merges, the
             # partition is at its fixed point — re-running the pass on
             # an unchanged state is a provable bitwise no-op (the parent
@@ -659,85 +664,93 @@ class StoCFLStrategy(Strategy):
                 pad = jnp.full((k_bound,), cap, jnp.int32)
                 return d, pad, pad, jnp.zeros((k_bound,), jnp.float32)
 
-            dcs, rows_live, new_roots, counts_c = jax.lax.cond(
-                run_merge, do_merge, skip_merge, dcs)
+            with jax.named_scope("merge_pass"):
+                dcs, rows_live, new_roots, counts_c = jax.lax.cond(
+                    run_merge, do_merge, skip_merge, dcs)
             # --- count-weighted bank merge (ClusterBank.merge, row-keyed;
             # the heavy θ segment-sums are cond-skipped on merge-free
             # rounds, mirroring ClusterBank.merge's early return)
-            mapped = ids_arr.at[rows_live].set(new_roots, mode="drop")
-            w_full = jnp.zeros((cap,), jnp.float32).at[rows_live].set(
-                counts_c.astype(jnp.float32), mode="drop")
-            gsize = jax.ops.segment_sum((w_full > 0).astype(jnp.int32),
-                                        mapped, num_segments=cap)
-            merged = gsize > 1
-            any_merged = jnp.any(merged)
-            settled = jnp.where(run_merge, ~any_merged, settled)
-            absorbed = (w_full > 0) & (mapped != ids_arr)
+            with jax.named_scope("bank_merge"):
+                mapped = ids_arr.at[rows_live].set(new_roots, mode="drop")
+                w_full = jnp.zeros((cap,), jnp.float32).at[rows_live].set(
+                    counts_c.astype(jnp.float32), mode="drop")
+                gsize = jax.ops.segment_sum(
+                    (w_full > 0).astype(jnp.int32), mapped,
+                    num_segments=cap)
+                merged = gsize > 1
+                any_merged = jnp.any(merged)
+                settled = jnp.where(run_merge, ~any_merged, settled)
+                absorbed = (w_full > 0) & (mapped != ids_arr)
 
-            def bank_merge(operand):
-                rows, has = operand
-                theta_full = jax.tree.map(
+                def bank_merge(operand):
+                    rows, has = operand
+                    theta_full = jax.tree.map(
+                        lambda R, I: jnp.where(
+                            _row_mask(has, R), R,
+                            jnp.asarray(I)[None].astype(R.dtype)),
+                        rows, cs["init"])
+                    denom = jax.ops.segment_sum(w_full, mapped,
+                                                num_segments=cap)
+                    wn = jnp.where(denom[mapped] > 0,
+                                   w_full / denom[mapped], 0.0)
+                    agg = jax.tree.map(
+                        lambda x: jax.ops.segment_sum(
+                            x * _row_mask(wn, x), mapped,
+                            num_segments=cap).astype(x.dtype), theta_full)
+                    rows = jax.tree.map(
+                        lambda R, A: jnp.where(_row_mask(merged, R),
+                                               A.astype(R.dtype), R),
+                        rows, agg)
+                    return rows, (has & ~absorbed) | merged
+
+                rows, has = jax.lax.cond(any_merged, bank_merge,
+                                         lambda o: o, (rows, has))
+            # --- bi-level cohort step over post-merge cluster models
+            with jax.named_scope("cohort_gather"):
+                r_ids = jnp.take(dcs.parent, ids)  # fully compressed roots
+                has_r = jnp.take(has, r_ids)
+                thetas = jax.tree.map(
                     lambda R, I: jnp.where(
-                        _row_mask(has, R), R,
+                        _row_mask(has_r, R[:1]), jnp.take(R, r_ids, axis=0),
                         jnp.asarray(I)[None].astype(R.dtype)),
                     rows, cs["init"])
-                denom = jax.ops.segment_sum(w_full, mapped,
-                                            num_segments=cap)
-                wn = jnp.where(denom[mapped] > 0,
-                               w_full / denom[mapped], 0.0)
-                agg = jax.tree.map(
-                    lambda x: jax.ops.segment_sum(
-                        x * _row_mask(wn, x), mapped,
-                        num_segments=cap).astype(x.dtype), theta_full)
+                thetas = specs.constrain_cohort(thetas, mesh)
+            with jax.named_scope("local_update"):
+                thetas_i, omegas_i = cohort(thetas, omega, batches)
+            with jax.named_scope("aggregation"):
+                w = jnp.take(cs["sizes"], ids)
+                omega = AGGREGATORS[aggname](omegas_i, w)
+                # per-cluster FedAvg over COMPACT cohort slots (≤ m), then
+                # a scatter of just the touched root rows: same segment
+                # sums in the same cohort order as the eager unique-root
+                # path, but the per-round bank traffic is O(m·|θ|), not
+                # O(capacity·|θ|) — the scan's write-back stays cluster-
+                # sized no matter how big the federation's row space is
+                pos = jnp.arange(m, dtype=jnp.int32)
+                firsts = jnp.argmax(r_ids[:, None] == r_ids[None, :],
+                                    axis=1).astype(jnp.int32)
+                is_first = firsts == pos
+                slot_of_pos = jnp.cumsum(is_first.astype(jnp.int32)) - 1
+                slot = jnp.take(slot_of_pos, firsts)
+                agg2 = bilevel.aggregate_segments(thetas_i, w, slot, m)
+                target = jnp.where(is_first, r_ids, cap).astype(jnp.int32)
                 rows = jax.tree.map(
-                    lambda R, A: jnp.where(_row_mask(merged, R),
-                                           A.astype(R.dtype), R),
-                    rows, agg)
-                return rows, (has & ~absorbed) | merged
-
-            rows, has = jax.lax.cond(any_merged, bank_merge,
-                                     lambda o: o, (rows, has))
-            # --- bi-level cohort step over post-merge cluster models
-            r_ids = jnp.take(dcs.parent, ids)      # fully compressed roots
-            has_r = jnp.take(has, r_ids)
-            thetas = jax.tree.map(
-                lambda R, I: jnp.where(_row_mask(has_r, R[:1]),
-                                       jnp.take(R, r_ids, axis=0),
-                                       jnp.asarray(I)[None].astype(R.dtype)),
-                rows, cs["init"])
-            thetas = specs.constrain_cohort(thetas, mesh)
-            thetas_i, omegas_i = cohort(thetas, omega, batches)
-            w = jnp.take(cs["sizes"], ids)
-            omega = AGGREGATORS[aggname](omegas_i, w)
-            # per-cluster FedAvg over COMPACT cohort slots (≤ m), then a
-            # scatter of just the touched root rows: same segment sums
-            # in the same cohort order as the eager unique-root path,
-            # but the per-round bank traffic is O(m·|θ|), not
-            # O(capacity·|θ|) — the scan's write-back stays cluster-
-            # sized no matter how big the federation's row space is
-            pos = jnp.arange(m, dtype=jnp.int32)
-            firsts = jnp.argmax(r_ids[:, None] == r_ids[None, :],
-                                axis=1).astype(jnp.int32)
-            is_first = firsts == pos
-            slot_of_pos = jnp.cumsum(is_first.astype(jnp.int32)) - 1
-            slot = jnp.take(slot_of_pos, firsts)
-            agg2 = bilevel.aggregate_segments(thetas_i, w, slot, m)
-            target = jnp.where(is_first, r_ids, cap).astype(jnp.int32)
-            rows = jax.tree.map(
-                lambda R, A: R.at[target].set(
-                    jnp.take(A, slot, axis=0).astype(R.dtype),
-                    mode="drop"),
-                rows, agg2)
-            has = has.at[target].set(True, mode="drop")
-            n_clusters = jnp.sum(dcs.live
-                                 & (dcs.parent == ids_arr)).astype(jnp.int32)
-            # Eq. 2 only moves when the partition does (observe or
-            # merge); otherwise the carried value IS this round's exact
-            # objective (same partition, deterministic reduction), so
-            # the O(capacity·D) recompute is cond-skipped
-            obj = jax.lax.cond(new_any | any_merged,
-                               devclust.objective_closed_impl,
-                               lambda _d: obj, dcs)
+                    lambda R, A: R.at[target].set(
+                        jnp.take(A, slot, axis=0).astype(R.dtype),
+                        mode="drop"),
+                    rows, agg2)
+                has = has.at[target].set(True, mode="drop")
+            with jax.named_scope("objective"):
+                n_clusters = jnp.sum(
+                    dcs.live & (dcs.parent == ids_arr)).astype(jnp.int32)
+                # Eq. 2 only moves when the partition does (observe or
+                # merge); otherwise the carried value IS this round's
+                # exact objective (same partition, deterministic
+                # reduction), so the O(capacity·D) recompute is
+                # cond-skipped
+                obj = jax.lax.cond(new_any | any_merged,
+                                   devclust.objective_closed_impl,
+                                   lambda _d: obj, dcs)
             rec = {"n_clusters": n_clusters,
                    "objective": obj,
                    "sampled": jnp.int32(m)}
@@ -745,27 +758,39 @@ class StoCFLStrategy(Strategy):
 
         def finalize(state, carry, ys, rounds):
             key, omega, dcs, rows, has, obj, settled = carry
-            clusters = devclust.DeviceClusters.from_arrays(
-                tau, np.asarray(dcs.parent), np.asarray(dcs.live),
-                np.asarray(dcs.rep))
-            roots = [int(r) for r in np.nonzero(np.asarray(has))[0]]
-            models = ClusterBank.from_dict(
-                {r: jax.tree.map(lambda R, rr=r: R[rr], rows)
-                 for r in roots})
-            # stash the carry for the warm-resume path (see scan_round):
-            # keyed by the exact objects returned below, so any state
-            # transition between spans invalidates it. The carried obj
-            # always equals objective_closed(dcs) (it is recomputed on
-            # every partition change), and a True settled flag only
-            # skips a merge pass that is a provable no-op on this
-            # partition — both are bitwise-safe to resume.
-            ctx.cache["stocfl_scan_resume"] = dict(
-                models=models, clusters=clusters, dcs=dcs, rows=rows,
-                has=has, obj=obj, settled=settled)
-            return state.replace(
-                omega=omega, rng_key=key, clusters=clusters, models=models,
-                round=state.round + rounds,
-                history=state.history + _scan_history(ys, rounds))
+            # the host hand-off in three spans: waiting for the scan,
+            # the device→host copies, the host-side rebuild
+            ids = dict(round=state.round, rounds=rounds)
+            fetched = (dcs.parent, dcs.live, dcs.rep, has, ys)
+            with jax.profiler.TraceAnnotation("repro.finalize.wait", **ids):
+                jax.block_until_ready(fetched)
+            with jax.profiler.TraceAnnotation("repro.finalize.fetch",
+                                              **ids):
+                parent, live, rep, has_np, ys = jax.tree.map(
+                    np.asarray, fetched)
+            with jax.profiler.TraceAnnotation("repro.finalize.rebuild",
+                                              **ids):
+                clusters = devclust.DeviceClusters.from_arrays(
+                    tau, parent, live, rep)
+                roots = [int(r) for r in np.nonzero(has_np)[0]]
+                models = ClusterBank.from_dict(
+                    {r: jax.tree.map(lambda R, rr=r: R[rr], rows)
+                     for r in roots})
+                # stash the carry for the warm-resume path (see
+                # scan_round): keyed by the exact objects returned below,
+                # so any state transition between spans invalidates it.
+                # The carried obj always equals objective_closed(dcs) (it
+                # is recomputed on every partition change), and a True
+                # settled flag only skips a merge pass that is a provable
+                # no-op on this partition — both are bitwise-safe to
+                # resume.
+                ctx.cache["stocfl_scan_resume"] = dict(
+                    models=models, clusters=clusters, dcs=dcs, rows=rows,
+                    has=has, obj=obj, settled=settled)
+                return state.replace(
+                    omega=omega, rng_key=key, clusters=clusters,
+                    models=models, round=state.round + rounds,
+                    history=state.history + _scan_history(ys, rounds))
 
         return carry0, consts, step, finalize, (ragged, cap, k_bound)
 

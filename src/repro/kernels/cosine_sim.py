@@ -88,10 +88,12 @@ def _padded(x, bn: int, bk: int):
     return xp, inv
 
 
-def _tiled_call(kernel, xp, row_vecs, bn: int, bk: int, interpret: bool):
-    """Run ``kernel`` over the (N/bn, N/bn, D/bk) grid. Each (n_pad,)
-    vector in ``row_vecs`` is passed twice: as a column for the tile's
-    rows (``*_i_ref``), then as a row for its columns (``*_j_ref``)."""
+def _tiled_call(kernel, name: str, xp, row_vecs, bn: int, bk: int,
+                interpret: bool):
+    """Run ``kernel`` over the (N/bn, N/bn, D/bk) grid as the op ``name``.
+    Each (n_pad,) vector in ``row_vecs`` is passed twice: as a column for
+    the tile's rows (``*_i_ref``), then as a row for its columns
+    (``*_j_ref``)."""
     n_pad, d_pad = xp.shape
     vec_specs = [pl.BlockSpec((bn, 1), lambda i, j, k: (i, 0)),
                  pl.BlockSpec((1, bn), lambda i, j, k: (0, j))] * len(row_vecs)
@@ -108,6 +110,7 @@ def _tiled_call(kernel, xp, row_vecs, bn: int, bk: int, interpret: bool):
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, n_pad), jnp.float32),
         interpret=interpret,
+        name=name,
     )(xp, xp, *vec_args)
 
 
@@ -120,7 +123,8 @@ def cosine_sim(x, *, bn: int = 128, bk: int = 512, interpret: bool = False):
     """
     n = x.shape[0]
     xp, inv = _padded(x, bn, bk)
-    return _tiled_call(_cosine_kernel, xp, [inv], bn, bk, interpret)[:n, :n]
+    return _tiled_call(_cosine_kernel, "cosine_sim", xp, [inv], bn, bk,
+                       interpret)[:n, :n]
 
 
 @functools.partial(jax.jit,
@@ -141,4 +145,5 @@ def merge_candidates(x, live, *, tau: float, bn: int = 128, bk: int = 512,
     lv = jnp.pad(live.astype(jnp.float32), (0, xp.shape[0] - n))
     # jaxlint: disable=R2 — tau is static (static_argnames), baked into the kernel
     kernel = functools.partial(_candidates_kernel, float(tau), bn)
-    return _tiled_call(kernel, xp, [inv, lv], bn, bk, interpret)[:n, :n]
+    return _tiled_call(kernel, "merge_candidates", xp, [inv, lv], bn, bk,
+                       interpret)[:n, :n]

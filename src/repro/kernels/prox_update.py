@@ -85,6 +85,7 @@ def _prox_call(theta, omega, g_theta, g_omega, eta, lam, *,
         # copy semantics — still correct, just not in-place)
         input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
+        name="prox_update",
     )(*slabs, eta_v, lam_v)
     th_out, om_out = (o.reshape(n_pad) for o in outs)
     if n_pad != n:

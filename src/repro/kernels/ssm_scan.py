@@ -66,5 +66,6 @@ def ssm_scan(dA, dBx, C, *, bd: int = 128, chunk: int = 128, interpret: bool = F
         out_shape=jax.ShapeDtypeStruct((B, s_pad, d_pad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
         interpret=interpret,
+        name="ssm_scan",
     )(dA_p, dBx_p, C_p)
     return y[:, :S, :D]
