@@ -672,15 +672,25 @@ class DeviceClusters:
 
     @classmethod
     def from_arrays(cls, tau: float, parent, live, rep) -> "DeviceClusters":
-        """Rebuild from checkpointed arrays (exact mirror restore)."""
+        """Rebuild from checkpointed host arrays (exact mirror restore):
+        all three are uploaded."""
+        return cls.from_state(tau, DeviceClusterState(
+            parent=jnp.asarray(parent, jnp.int32),
+            live=jnp.asarray(live, bool),
+            rep=jnp.asarray(rep, jnp.float32)), parent, live)
+
+    @classmethod
+    def from_state(cls, tau: float, state: DeviceClusterState, parent,
+                   live) -> "DeviceClusters":
+        """Wrap a device state as it is, with no copy. ``parent`` and
+        ``live`` are host copies of ``state.parent`` and ``state.live``:
+        the only arrays the host mirrors (``_parent``, ``seen``) read,
+        so the (capacity, D) Ψ bank never crosses to the host."""
         out = cls(tau, capacity=max(len(parent), 1))
         if len(parent):
-            out._state = DeviceClusterState(
-                parent=jnp.asarray(parent, jnp.int32),
-                live=jnp.asarray(live, bool),
-                rep=jnp.asarray(rep, jnp.float32))
+            out._state = state
             out.seen = {int(i) for i in np.nonzero(np.asarray(live))[0]}
-            out._parent = np.asarray(parent).astype(np.int64).copy()
+            out._parent = np.asarray(parent).astype(np.int64)
         return out
 
     def __repr__(self) -> str:

@@ -757,28 +757,52 @@ class StoCFLStrategy(Strategy):
             return (key, omega, dcs, rows, has, obj, settled), rec
 
         def finalize(state, carry, ys, rounds):
+            """The host hand-off after the scan. Only what the host
+            reads crosses to it: the partition's ``parent`` and ``live``
+            (the host mirrors of ``DeviceClusters``), the bank flags
+            ``has`` and the per-round metrics — a few KB. The Ψ bank
+            ``dcs.rep`` stays the carry's device buffer, held by the
+            returned state's ``clusters``; the model rows stay in the
+            warm-resume stash, and the returned ``ClusterBank`` is one
+            device gather of them. A donating backend consumes the
+            carry with the state on its next ``run_rounds`` call, as it
+            does ω."""
             key, omega, dcs, rows, has, obj, settled = carry
             # the host hand-off in three spans: waiting for the scan,
-            # the device→host copies, the host-side rebuild
+            # the device→host copies (their total size as ``bytes``),
+            # the host-side rebuild
             ids = dict(round=state.round, rounds=rounds)
-            fetched = (dcs.parent, dcs.live, dcs.rep, has, ys)
+            fetched = (dcs.parent, dcs.live, has, ys)
             with jax.profiler.TraceAnnotation("repro.finalize.wait", **ids):
                 jax.block_until_ready(fetched)
+            nbytes = sum(x.nbytes for x in jax.tree.leaves(fetched))
             with jax.profiler.TraceAnnotation("repro.finalize.fetch",
-                                              **ids):
-                parent, live, rep, has_np, ys = jax.tree.map(
-                    np.asarray, fetched)
+                                              bytes=nbytes, **ids):
+                parent, live, has_np, ys = jax.device_get(fetched)
             with jax.profiler.TraceAnnotation("repro.finalize.rebuild",
                                               **ids):
-                clusters = devclust.DeviceClusters.from_arrays(
-                    tau, parent, live, rep)
+                clusters = devclust.DeviceClusters.from_state(
+                    tau, dcs, parent, live)
                 roots = [int(r) for r in np.nonzero(has_np)[0]]
-                models = ClusterBank.from_dict(
-                    {r: jax.tree.map(lambda R, rr=r: R[rr], rows)
-                     for r in roots})
+                models = ClusterBank.empty()
+                if roots:
+                    # the bank's rows in ascending root order, spare rows
+                    # zero (out-of-range index → fill): one jitted
+                    # gather, not one eager slice per root and leaf
+                    bcap = bank_pow2(len(roots))
+                    idx = np.full(bcap, cap, np.int32)
+                    idx[:len(roots)] = roots
+                    take = ctx.jit(
+                        f"stocfl_bank_take:{cap}:{bcap}",
+                        lambda: jax.jit(lambda R, i: jax.tree.map(
+                            lambda x: jnp.take(x, i, axis=0, mode="fill",
+                                               fill_value=0), R)))
+                    models = ClusterBank(take(rows, jnp.asarray(idx)),
+                                         roots)
                 # stash the carry for the warm-resume path (see
                 # scan_round): keyed by the exact objects returned below,
                 # so any state transition between spans invalidates it.
+                # Its dcs IS clusters.state (the same device arrays).
                 # The carried obj always equals objective_closed(dcs) (it
                 # is recomputed on every partition change), and a True
                 # settled flag only skips a merge pass that is a provable
