@@ -4,7 +4,8 @@ comparison with the reference. Everything a cell needs is found by name:
   BENCHMARK.json                       the cell, its configuration, its metrics
   bench/configs/<config>.json          the configuration as it is run
   bench/configs/<config>.py            its plain model reference, FLOP
-                                       count and the program's loss
+                                       count and the program's loss, and
+                                       the optional hooks below
   bench/traffic/<traffic>.json         the traffic mix's parameters
   bench/traffic/<kind>.py              the driver of the mix's ``kind``
   bench/metrics/<metric>.py            one reader per per-layer metric
@@ -16,6 +17,28 @@ sampling, driven through ``engine.run_rounds``. The data comes from the
 run's seed; the initial weights and the engine's sampling key come from
 the configuration's ``fixed_seeds``, so every seed runs the same
 compiled programs on the same arrival order.
+
+A configuration module always defines ``init_params(key, model)``,
+``apply(params, x, model)`` and ``forward_flops(model)``, and
+``program_loss(model)`` unless it defines ``program_init``. It may
+define, and where it does not the harness does what the default says:
+
+  frozen_params(key, model)   a tree of weights that is not trained (a
+                              frozen backbone), drawn on the device from
+                              ``fixed_seeds.frozen``; the reference's
+                              ``apply`` then takes it as a fourth
+                              argument, ``apply(params, x, model,
+                              frozen)``. Default: none.
+  program_init(model, frozen, strategy, params0, clients, ecfg, mesh)
+                              the program's initial ``ServerState``.
+                              Default: ``engine.init(strategy,
+                              program_loss(model), params0, clients,
+                              ecfg, mesh=mesh, arena=True)``.
+  gradient_flops(model)       model FLOPs of one example's gradient.
+                              Default: ``3 * forward_flops(model)``.
+
+and its JSON file may hold ``reference.client_block``, the number of
+clients the reference runs at once (default: the whole cohort).
 """
 from __future__ import annotations
 
@@ -99,6 +122,7 @@ class Program:
         init = jax.jit(lambda k: cell.model_ref.init_params(k, model))
         fixed = cfg["fixed_seeds"]
         params0 = init(federation.key(fixed["weights"], "weights"))
+        frozen = frozen_params(cell)
         self.omega0 = jax.device_get(params0)
         self.n_params = sum(int(np.prod(l.shape))
                             for l in jax.tree.leaves(self.omega0))
@@ -115,12 +139,38 @@ class Program:
         if cell.chips > 1:
             from repro.launch.mesh import make_client_mesh
             mesh = make_client_mesh(cell.chips)
-        self.state0 = engine.init(
-            t["strategy"], cell.model_ref.program_loss(model), params0,
-            clients, self.ecfg, mesh=mesh, arena=True)
+        program_init = getattr(cell.model_ref, "program_init", None)
+        if program_init is None:
+            self.state0 = engine.init(
+                t["strategy"], cell.model_ref.program_loss(model), params0,
+                clients, self.ecfg, mesh=mesh, arena=True)
+        else:
+            self.state0 = program_init(model, frozen, t["strategy"],
+                                       params0, clients, self.ecfg, mesh)
         self.n_clients = len(clients)
         self.n_per = int(fed["n_per"])
         self.m = int(math.ceil(self.ecfg.sample_rate * self.n_clients))
+
+
+def frozen_params(cell: Cell):
+    """The configuration's frozen tree, drawn in one jitted call on the
+    device from ``fixed_seeds.frozen``; None where it has none."""
+    make = getattr(cell.model_ref, "frozen_params", None)
+    if make is None:
+        return None
+    model = cell.config["model"]
+    k = federation.key(cell.config["fixed_seeds"]["frozen"], "frozen")
+    return jax.jit(lambda kk: make(kk, model))(k)
+
+
+def gradient_flops(cell: Cell) -> int:
+    """Model FLOPs of one example's gradient: the configuration's own
+    count, or forward + backward = 3 forwards where every weight is
+    trained."""
+    model, ref = cell.config["model"], cell.model_ref
+    if hasattr(ref, "gradient_flops"):
+        return int(ref.gradient_flops(model))
+    return 3 * int(ref.forward_flops(model))
 
 
 def snapshot(state):
@@ -223,11 +273,10 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool,
     failed = 0 if drv.finite() else attempted
     mem = memory_peak(cell.chips)
 
-    fwd = int(cell.model_ref.forward_flops(cell.config["model"]))
     steps = int(prog.training["local_steps"])
-    # model FLOPs: forward + backward = 3 forwards per example; two
-    # gradients (θ and ω) per local step; one more per new client's Ψ
-    flops_unit = prog.n_per * 3 * fwd * (
+    # model FLOPs: two gradients (θ and ω) per example and local step,
+    # one more per example of each new client's Ψ
+    flops_unit = prog.n_per * gradient_flops(cell) * (
         (rounds // max(units, 1)) * prog.m * steps * 2 + drv.psi_per_unit())
     view = {
         "trace": result_trace, "window_s": window_s, "chips": cell.chips,
@@ -283,8 +332,16 @@ def reference_numbers(cell: Cell, seed: int, handoff, rounds: int, omega0,
     x, y, _ = federation.generate(cell.config["federation"],
                                   model["input_shape"], model["n_classes"],
                                   seed)
-    ref = Reference(lambda p, xb: cell.model_ref.apply(p, xb, model),
-                    cell.config["training"], x, y, omega0, ecfg.seed,
-                    ecfg.sample_rate)
+    frozen = frozen_params(cell)
+    if frozen is None:
+        def apply(p, xb, fz):
+            return cell.model_ref.apply(p, xb, model)
+    else:
+        def apply(p, xb, fz):
+            return cell.model_ref.apply(p, xb, model, fz)
+    ref = Reference(apply, cell.config["training"], x, y, omega0, ecfg.seed,
+                    ecfg.sample_rate, frozen=frozen,
+                    client_block=cell.config.get("reference", {}).get(
+                        "client_block"))
     ref.run(rounds)
     return checks.compare(handoff, ref.result(), omega0)

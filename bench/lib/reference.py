@@ -21,6 +21,16 @@ Per round, from the sampling key ``PRNGKey(engine seed)``:
   5. omega <- the size-weighted mean of the members' omegas; each touched
      cluster's model <- the size-weighted mean of its members' thetas.
 
+The loss is the mean cross-entropy over every target: one per example
+for (B, C) logits, one per position for (B, T, V) logits. ``apply(params,
+x, frozen)`` gives the logits; ``frozen`` is a configuration's frozen
+tree (None where it has none), passed to every compiled program as an
+argument, never closed over, so no program holds it as a constant.
+``client_block`` runs the Psi and the local updates of a round's clients
+in blocks of that many (``lax.map`` over blocks of a vmap), so that the
+reference fits at a model's full widths; None runs the whole cohort as
+one vmap.
+
 Float work runs in float32 at the highest matmul precision. The cosines
 of the merge pass come from a float64 Gram matrix of the Psi rows on the
 host, so no (K, D) product of cluster means is ever formed.
@@ -43,8 +53,8 @@ class Reference:
     STRATEGY = "stocfl"
 
     def __init__(self, apply, training: dict, x, y, omega0, engine_seed: int,
-                 sample_rate: float):
-        self.x, self.y = x, y
+                 sample_rate: float, frozen=None, client_block=None):
+        self.x, self.y, self.frozen = x, y, frozen
         self.n = int(x.shape[0])
         self.cap = _pow2(self.n)
         self.m = min(int(math.ceil(float(sample_rate) * self.n)), self.n)
@@ -66,45 +76,56 @@ class Reference:
         dim = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(omega0))
         self.bank = jnp.zeros((self.cap, dim), jnp.float32)
 
-        def loss(p, xb, yb):
-            logits = apply(p, xb).astype(jnp.float32)
+        def loss(p, xb, yb, fz):
+            logits = apply(p, xb, fz).astype(jnp.float32)
             logz = jax.scipy.special.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            gold = jnp.take_along_axis(logits, yb[..., None], axis=-1)[..., 0]
             return jnp.mean(logz - gold)
 
         grad = jax.grad(loss)
         m = self.m
+        block = (None if client_block is None or int(client_block) >= m
+                 else int(client_block))
 
         def draw(key, pool):
             key, sub = jax.random.split(key)
             u = jnp.where(pool, jax.random.uniform(sub, pool.shape), jnp.inf)
             return key, jnp.argsort(u)[:m].astype(jnp.int32)
 
-        def psi(xb, yb):
-            g = grad(self.omega0, xb, yb)
+        def psi(xb, yb, fz):
+            g = grad(self.omega0, xb, yb, fz)
             v = jnp.concatenate([jnp.ravel(l).astype(jnp.float32)
                                  for l in jax.tree.leaves(g)])
             nrm = jnp.linalg.norm(v)
             return jnp.where(nrm > 0, v / nrm, v)
 
-        def observe(bank, ids, new, xs, ys):
-            p = jax.vmap(psi)(xs, ys)
+        def observe(bank, ids, new, xs, ys, fz):
+            if block is None:
+                p = jax.vmap(psi, in_axes=(0, 0, None))(xs, ys, fz)
+            else:
+                p = jax.lax.map(lambda a: psi(*a, fz), (xs, ys),
+                                batch_size=block)
             idx = jnp.where(new, ids, self.cap)
             bank = bank.at[idx].set(p, mode="drop")
             return bank, p @ bank.T
 
-        def client(theta, omega, xb, yb):
+        def client(theta, omega, xb, yb, fz):
             for _ in range(steps):
-                gt, go = grad(theta, xb, yb), grad(omega, xb, yb)
+                gt, go = grad(theta, xb, yb, fz), grad(omega, xb, yb, fz)
                 theta = jax.tree.map(
                     lambda t, g, o: t - lr * (g + lam * (t - o)),
                     theta, gt, omega)
                 omega = jax.tree.map(lambda o, g: o - lr * g, omega, go)
             return theta, omega
 
-        def cohort(thetas, omega, xs, ys, w, seg):
-            th, om = jax.vmap(client, in_axes=(0, None, 0, 0))(
-                thetas, omega, xs, ys)
+        def cohort(thetas, omega, xs, ys, w, seg, fz):
+            if block is None:
+                th, om = jax.vmap(client, in_axes=(0, None, 0, 0, None))(
+                    thetas, omega, xs, ys, fz)
+            else:
+                th, om = jax.lax.map(
+                    lambda a: client(a[0], omega, a[1], a[2], fz),
+                    (thetas, xs, ys), batch_size=block)
             wn = w / jnp.sum(w)
             omega = jax.tree.map(
                 lambda a: jnp.tensordot(wn, a, axes=1), om)
@@ -136,7 +157,7 @@ class Reference:
         new = ~self.live[ids_h]
         if new.any():
             self.bank, rows = self._observe(self.bank, ids, jnp.asarray(new),
-                                            xs, ys)
+                                            xs, ys, self.frozen)
             rows = np.asarray(rows, np.float64)[:, : self.n]
             for j in np.nonzero(new)[0]:
                 c = ids_h[j]
@@ -153,7 +174,7 @@ class Reference:
                                for r in roots])
         self.omega, per = self._cohort(
             thetas, self.omega, xs, ys, jnp.asarray(self.sizes[ids_h]),
-            jnp.asarray(slot, jnp.int32))
+            jnp.asarray(slot, jnp.int32), self.frozen)
         for k, r in enumerate(uniq):
             self.models[r] = self._pick(per, jnp.int32(k))
 
