@@ -9,6 +9,13 @@ Server (lines 17-19): ω ← Aggregate([ωᵢ]) over all sampled clients;
 ``make_client_update`` returns a jitted, vmappable function — the whole
 sampled cohort executes as ONE SPMD computation with clients stacked on
 the leading axis (the mesh's client/data axis in production).
+
+The loss is ``loss(params, batch)``, or ``loss(params, batch, frozen)``
+where the model reads a tree that is not trained (a frozen backbone):
+the update then takes that tree as an argument, so a compiled cohort
+step never holds it as a constant. A loss may return ``(loss, stats)``
+with ``stats`` a dict of scalar counters of its forward pass; the update
+then also returns them, combined over its gradients by ``merge_stats``.
 """
 from __future__ import annotations
 
@@ -48,61 +55,103 @@ def unflatten_tree(vec, spec):
         [p.reshape(s).astype(d) for p, s, d in zip(parts, shapes, dtypes)])
 
 
+def loss_args(frozen) -> tuple:
+    """The loss's arguments after ``(params, batch)``: the frozen tree,
+    or none at all where there is no such tree."""
+    return () if frozen is None else (frozen,)
+
+
+def merge_stats(a: dict, b: dict) -> dict:
+    """Combine two dicts of forward-pass counters: a counter whose name
+    holds ``max`` keeps the larger value, every other one adds up."""
+    return {k: (jnp.maximum if "max" in k else jnp.add)(a[k], b[k])
+            for k in a}
+
+
+def _grad_with_stats(loss_fn):
+    """``grad(params, batch, *frozen) -> (grads, stats)``; ``stats`` is
+    ``{}`` for a loss that returns a scalar."""
+    def loss_stats(*args):
+        out = loss_fn(*args)
+        return out if isinstance(out, tuple) else (out, {})
+
+    return jax.grad(loss_stats, has_aux=True)
+
+
 def make_client_update(loss_fn: Callable, lr: float, lam: float,
                        local_steps: int = 1, backend: str = "auto",
                        fused: bool = False):
-    """loss_fn(params, batch) -> scalar.
+    """loss_fn(params, batch[, frozen]) -> scalar or (scalar, stats).
 
-    Returns client_update(theta, omega, batch) -> (theta_i, omega_i):
-    E = local_steps full-batch SGD steps of the bi-level objective.
+    Returns client_update(theta, omega, batch, frozen=None) ->
+    (theta_i, omega_i), or (theta_i, omega_i, stats) where the loss
+    returns counters (combined over the update's 2·E gradients): E =
+    local_steps full-batch SGD steps of the bi-level objective.
 
     ``fused=True`` flattens θ/ω ONCE, runs the E-step scan on the flat
     vectors with the fused ``prox_update_flat`` kernel (jnp oracle
     off-TPU — same f32-accumulate formula, so fused/tree agree bitwise
     in fp32), and unflattens once at the end. Grads still see the
     original pytree via a per-step unflatten view."""
-    grad_fn = jax.grad(loss_fn)
+    grad_fn = _grad_with_stats(loss_fn)
+
+    def finish(th, om, stats):
+        return (th, om, stats) if stats else (th, om)
+
+    def stats0(theta, batch, extra):
+        _, st = jax.eval_shape(grad_fn, theta, batch, *extra)
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), st)
 
     if fused:
-        def client_update(theta, omega, batch):
+        def client_update(theta, omega, batch, frozen=None):
+            extra = loss_args(frozen)
             spec = flat_spec(theta)
             th_f = flatten_tree(theta)
             om_f = flatten_tree(omega)
 
             def step(carry, _):
-                thf, omf = carry
-                g_t = flatten_tree(grad_fn(unflatten_tree(thf, spec), batch))
-                g_o = flatten_tree(grad_fn(unflatten_tree(omf, spec), batch))
-                thf, omf = ops.prox_update_flat(thf, omf, g_t, g_o, lr, lam,
-                                                backend=backend)
-                return (thf, omf), None
+                thf, omf, st = carry
+                g_t, s_t = grad_fn(unflatten_tree(thf, spec), batch, *extra)
+                g_o, s_o = grad_fn(unflatten_tree(omf, spec), batch, *extra)
+                thf, omf = ops.prox_update_flat(
+                    thf, omf, flatten_tree(g_t), flatten_tree(g_o), lr, lam,
+                    backend=backend)
+                return (thf, omf, merge_stats(merge_stats(st, s_t), s_o)), None
 
-            (th_f, om_f), _ = jax.lax.scan(step, (th_f, om_f), None,
-                                           length=local_steps)
-            return unflatten_tree(th_f, spec), unflatten_tree(om_f, spec)
+            (th_f, om_f, st), _ = jax.lax.scan(
+                step, (th_f, om_f, stats0(theta, batch, extra)), None,
+                length=local_steps)
+            return finish(unflatten_tree(th_f, spec),
+                          unflatten_tree(om_f, spec), st)
 
         return client_update
 
-    def client_update(theta, omega, batch):
-        def step(carry, _):
-            th, om = carry
-            g_t = grad_fn(th, batch)
-            g_o = grad_fn(om, batch)
-            th, om = ops.prox_update_tree(th, om, g_t, g_o, lr, lam, backend=backend)
-            return (th, om), None
+    def client_update(theta, omega, batch, frozen=None):
+        extra = loss_args(frozen)
 
-        (th, om), _ = jax.lax.scan(step, (theta, omega), None, length=local_steps)
-        return th, om
+        def step(carry, _):
+            th, om, st = carry
+            g_t, s_t = grad_fn(th, batch, *extra)
+            g_o, s_o = grad_fn(om, batch, *extra)
+            th, om = ops.prox_update_tree(th, om, g_t, g_o, lr, lam, backend=backend)
+            return (th, om, merge_stats(merge_stats(st, s_t), s_o)), None
+
+        (th, om, st), _ = jax.lax.scan(
+            step, (theta, omega, stats0(theta, batch, extra)), None,
+            length=local_steps)
+        return finish(th, om, st)
 
     return client_update
 
 
 def make_cohort_update(loss_fn, lr, lam, local_steps=1, backend: str = "auto",
                        fused: bool = False, donate: bool = True):
-    """vmapped cohort step: thetas stacked per client, omega shared.
+    """vmapped cohort step: thetas stacked per client, omega and the
+    frozen tree (if any) shared.
 
     thetas: pytree with leading client axis; batches: stacked client
-    batches. Returns (thetas_i, omegas_i) both with client axis.
+    batches. Returns (thetas_i, omegas_i) both with client axis, and
+    the per-client counters where the loss returns them.
 
     Off-CPU the stacked cohort buffers (thetas, batches) are donated:
     both are per-round temporaries at every call site (thetas are
@@ -113,7 +162,13 @@ def make_cohort_update(loss_fn, lr, lam, local_steps=1, backend: str = "auto",
     built, which is per-EngineContext (not per-import)."""
     cu = make_client_update(loss_fn, lr, lam, local_steps, backend, fused=fused)
     dn = (0, 2) if (donate and jax.default_backend() != "cpu") else ()
-    return jax.jit(jax.vmap(cu, in_axes=(0, None, 0)), donate_argnums=dn)
+
+    @functools.wraps(cu)
+    def cohort(thetas, omega, batches, frozen=None):
+        return jax.vmap(cu, in_axes=(0, None, 0, None))(thetas, omega,
+                                                        batches, frozen)
+
+    return jax.jit(cohort, donate_argnums=dn)
 
 
 def chunk_map(fn, in_axes, chunk: int, donate=None):

@@ -18,6 +18,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import bilevel
 from repro.utils import trees
 
 
@@ -37,9 +38,14 @@ def make_extractor(loss_fn: Callable, anchor_params,
                    leaf_filter: Optional[Callable[[str], bool]] = None) -> Callable:
     """Returns Ψ: batch -> normalized representation vector.
 
-    loss_fn(params, batch) -> scalar. If ``batched``, the returned fn maps
-    a stacked client batch (leading client axis) to stacked representations
-    via vmap — the SPMD path used when clients ride the mesh's data axis.
+    loss_fn(params, batch) -> scalar, or loss_fn(params, batch, frozen)
+    where the model reads a frozen tree; Ψ is then ``psi(batch, frozen)``
+    and the tree an argument of its program, never a constant. A loss
+    that returns ``(loss, stats)`` has its stats dropped here. Ψ is the
+    gradient over the trained tree alone. If ``batched``, the returned fn
+    maps a stacked client batch (leading client axis) to stacked
+    representations via vmap — the SPMD path used when clients ride the
+    mesh's data axis.
 
     leaf_filter("path/to/leaf") -> bool restricts Ψ to a parameter subset.
     For LLM anchors the data-distribution signal concentrates in the
@@ -47,10 +53,14 @@ def make_extractor(loss_fn: Callable, anchor_params,
     per-token noise that drowns the cosine signal (see examples/
     federated_llm.py) — ``llm_leaf_filter`` keeps only those rows.
     """
-    grad_fn = jax.grad(loss_fn)
+    def scalar_loss(*args):
+        out = loss_fn(*args)
+        return out[0] if isinstance(out, tuple) else out
 
-    def psi(batch):
-        g = grad_fn(anchor_params, batch)
+    grad_fn = jax.grad(scalar_loss)
+
+    def psi(batch, frozen=None):
+        g = grad_fn(anchor_params, batch, *bilevel.loss_args(frozen))
         if leaf_filter is not None:
             flat = jax.tree_util.tree_flatten_with_path(g)[0]
             kept = [jnp.ravel(v) for kp, v in flat

@@ -34,15 +34,20 @@ from repro.engine.state import EngineConfig, EngineContext, ServerState
 
 def init(strategy: str, loss_fn, init_params, clients,
          cfg: Optional[EngineConfig] = None, eval_fn=None,
-         leaf_filter=None, mesh=None, arena: bool = False) -> ServerState:
+         leaf_filter=None, mesh=None, arena: bool = False,
+         frozen=None) -> ServerState:
     """Build the static context and the strategy's initial ``ServerState``.
 
     Args:
       strategy: registered strategy name (``engine.list_strategies()``) —
         ``"stocfl"`` (Algorithm 1) or one of the paper's §4 baselines.
-      loss_fn: ``(params, batch) -> scalar`` local objective f_i.
-      init_params: ω₀ — also the frozen Ψ anchor (§3.1) and the lazy
-        cluster-model default θ_k.
+      loss_fn: ``(params, batch) -> scalar`` local objective f_i, or
+        ``(params, batch, frozen) -> scalar`` with ``frozen`` given. It
+        may return ``(scalar, stats)``, ``stats`` a dict of scalar
+        counters of its forward pass, which land in each round's record
+        (``bilevel.merge_stats`` says how they combine).
+      init_params: ω₀ — the trained tree; also the frozen Ψ anchor
+        (§3.1) and the lazy cluster-model default θ_k.
       clients: list of client datasets (pytrees with a shared leading
         example axis).
       cfg: ``EngineConfig`` hyperparameters (strategy-specific subset).
@@ -60,6 +65,12 @@ def init(strategy: str, loss_fn, init_params, clients,
         the loss must then honor the batch's ``"mask"`` leaf).
         ``cfg.cohort_chunk`` bounds how many clients one vmapped step
         executes — see ``bilevel.chunk_map``.
+      frozen: optional tree the model reads and nothing trains (a
+        frozen backbone under trained adapters). It reaches every
+        compiled program — the Ψ extractor, the eager cohort step and
+        the ``run_rounds`` scan, where it rides the round-invariant
+        consts — as an argument, never as a closed-over constant, and
+        Ψ stays the gradient over ``init_params`` alone. StoCFL only.
 
     Returns:
       The strategy's initial ``ServerState`` (round 0, nothing trained).
@@ -69,13 +80,18 @@ def init(strategy: str, loss_fn, init_params, clients,
     # the anchor is frozen (§3.1), so embeddings/means/Eq. 2 keep full
     # precision while params/grads/batches run in cfg.dtype
     psi_anchor = init_params
+    strat = get_strategy(strategy)
+    if frozen is not None and not strat.takes_frozen:
+        raise ValueError(f"strategy {strategy!r} takes no frozen tree")
     if cfg.dtype != "float32":
         dt = _np_like_dtype(cfg.dtype)
         init_params = _cast_floating(init_params, dt)
         clients = [_cast_floating(c, dt) for c in clients]
+        if frozen is not None:
+            frozen = _cast_floating(frozen, dt)
     ctx = EngineContext(loss_fn=loss_fn, init_params=init_params,
                         clients=list(clients), cfg=cfg, eval_fn=eval_fn,
-                        leaf_filter=leaf_filter, mesh=mesh)
+                        leaf_filter=leaf_filter, mesh=mesh, frozen=frozen)
     if arena:
         from repro.data.arena import ClientArena
         from repro.sharding import specs as shard_specs
@@ -88,7 +104,6 @@ def init(strategy: str, loss_fn, init_params, clients,
         ctx.arena = ClientArena.from_clients(ctx.clients, capacity=cap)
         if mesh is not None:
             ctx.arena = ctx.arena.place(mesh)
-    strat = get_strategy(strategy)
     if strat.needs_extractor:
         ctx.extractor = make_extractor(loss_fn, psi_anchor, cfg.project_dim,
                                        leaf_filter=leaf_filter)

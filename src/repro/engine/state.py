@@ -10,10 +10,10 @@ client-axis mesh; host-side bookkeeping (partition, rng, round counter)
 rides along as aux data.
 
 ``EngineContext`` is the static world the state refers to: loss/eval
-functions, the client datasets, compiled cohort updates, the Ψ extractor
-and the optional mesh. It is built once by ``engine.init`` and is never
-checkpointed — restoring a checkpoint reattaches the arrays to a freshly
-built context.
+functions, the client datasets, compiled cohort updates, the Ψ extractor,
+the optional frozen tree the loss reads and the optional mesh. It is
+built once by ``engine.init`` and is never checkpointed — restoring a
+checkpoint reattaches the arrays to a freshly built context.
 """
 from __future__ import annotations
 
@@ -90,6 +90,7 @@ class EngineContext:
     leaf_filter: Optional[Callable] = None
     mesh: Optional[Any] = None        # jax Mesh: place cohort on client axis
     arena: Optional[Any] = None       # ClientArena: device-resident shards
+    frozen: Optional[Any] = None      # untrained tree the loss reads
     extractor: Optional[Callable] = None
     cache: Dict[str, Any] = dataclasses.field(default_factory=dict)
 

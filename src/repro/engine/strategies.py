@@ -149,6 +149,15 @@ def _sizes_f32(state: ServerState):  # jaxlint: hot-path
     return _sizes_f32_upload(tuple(state.sizes))
 
 
+def _cohort_stats(stats) -> dict:
+    """The round's forward-pass counters from the cohort step's optional
+    third output (per-client ``(m,)`` counters, in ``stats`` — an empty
+    list where the loss returns none), reduced over the cohort as
+    ``bilevel.merge_stats`` combines them over gradients."""
+    return {k: (jnp.max if "max" in k else jnp.sum)(v)
+            for per in stats for k, v in per.items()}
+
+
 def _row_mask(mask, leaf):
     """Broadcast a (rows,) bool mask against a (rows, ...) leaf."""
     return mask.reshape((-1,) + (1,) * (leaf.ndim - 1))
@@ -183,9 +192,9 @@ def _scan_consts(ctx: EngineContext, consts: dict) -> dict:
     """Pin the scan's const operands to the mesh: arena buffers keep
     their row sharding (leading capacity axis over the client devices —
     a no-op device_put when ``ClientArena.place`` already placed them),
-    everything else (pool mask, sizes, row map, ω₀) replicates. Without
-    a mesh this is the identity, so the single-device scan's operands
-    are untouched."""
+    everything else (pool mask, sizes, row map, ω₀, the frozen tree)
+    replicates. Without a mesh this is the identity, so the single-device
+    scan's operands are untouched."""
     if ctx.mesh is None:
         return consts
     out = {}
@@ -233,6 +242,7 @@ class Strategy:
     needs_extractor = False
     full_participation = False
     supports_async = False
+    takes_frozen = False        # engine.init(..., frozen=tree) allowed
 
     # ------------------------------------------------------------ lifecycle
     def init_state(self, ctx: EngineContext) -> ServerState:
@@ -335,6 +345,7 @@ class StoCFLStrategy(Strategy):
 
     needs_extractor = True
     supports_async = True
+    takes_frozen = True
 
     def init_state(self, ctx):
         """Adds the Ψ-clustering bookkeeping: the host ``ClusterState``
@@ -356,7 +367,7 @@ class StoCFLStrategy(Strategy):
                                        cfg.local_steps,
                                        backend="auto" if fused else "jnp",
                                        fused=fused),
-            (0, None, 0), _chunk(ctx)))
+            (0, None, 0, None), _chunk(ctx)))
 
     def round(self, ctx, state, client_ids):
         cfg = ctx.cfg
@@ -375,10 +386,11 @@ class StoCFLStrategy(Strategy):
             # source, so ragged federations stay scan-vs-eager exact
             if ctx.arena is not None:
                 reps = [ctx.extractor(jax.tree.map(
-                    lambda x: x[0], ctx.arena.gather([c])))
+                    lambda x: x[0], ctx.arena.gather([c])), ctx.frozen)
                     for c in new_ids]
             else:
-                reps = [ctx.extractor(ctx.clients[c]) for c in new_ids]
+                reps = [ctx.extractor(ctx.clients[c], ctx.frozen)
+                        for c in new_ids]
             clusters.observe(new_ids, reps)
         counts = {r: len(m) for r, m in clusters.clusters().items()}
         merges = clusters.merge_round()
@@ -397,7 +409,8 @@ class StoCFLStrategy(Strategy):
         thetas = _place(ctx, thetas)
         batches = _place(ctx, batches)
         omega = _place(ctx, state.omega, replicated=True)
-        thetas_i, omegas_i = self._cohort(ctx)(thetas, omega, batches)
+        thetas_i, omegas_i, *stats = self._cohort(ctx)(
+            thetas, omega, batches, ctx.frozen)
 
         w = _weights(state, client_ids)
         omega = AGGREGATORS[cfg.aggregator](omegas_i, w)
@@ -418,7 +431,9 @@ class StoCFLStrategy(Strategy):
             objective = clusters.objective()
         rec = {"n_clusters": clusters.n_clusters(),
                "objective": objective,
-               "sampled": len(client_ids)}
+               "sampled": len(client_ids),
+               **{k: int(v) for k, v in
+                  _cohort_stats(stats).items()}}
         return state.replace(omega=omega, models=models, clusters=clusters), rec
 
     # ------------------------------------------------------------ async
@@ -441,10 +456,11 @@ class StoCFLStrategy(Strategy):
             new_ids = [int(client_ids[i]) for i in new_pos]
             if ctx.arena is not None:
                 reps = [ctx.extractor(jax.tree.map(
-                    lambda x: x[0], ctx.arena.gather([c])))
+                    lambda x: x[0], ctx.arena.gather([c])), ctx.frozen)
                     for c in new_ids]
             else:
-                reps = [ctx.extractor(ctx.clients[c]) for c in new_ids]
+                reps = [ctx.extractor(ctx.clients[c], ctx.frozen)
+                        for c in new_ids]
             # the buffer IS the observe data path: Ψ rows in, Ψ rows out
             # (pure scatter/gather — the read-back is bit-identical)
             new_slots = np.asarray(slots)[new_pos]
@@ -469,7 +485,8 @@ class StoCFLStrategy(Strategy):
         thetas = _place(ctx, thetas)
         batches = _place(ctx, batches)
         omega = _place(ctx, state.omega, replicated=True)
-        thetas_i, omegas_i = self._cohort(ctx)(thetas, omega, batches)
+        thetas_i, omegas_i, *_ = self._cohort(ctx)(thetas, omega, batches,
+                                                   ctx.frozen)
         buf = buf.write(slots, thetas_i, omegas_i)
         return state.replace(models=models, clusters=clusters), buf
 
@@ -502,7 +519,8 @@ class StoCFLStrategy(Strategy):
         path in ``scan_round`` skips all of this for back-to-back
         ``run_rounds`` calls on an untouched state."""
         if clusters.state is None:
-            dim = int(np.shape(np.asarray(ctx.extractor(ctx.clients[0])))[0])
+            dim = int(jax.eval_shape(ctx.extractor, ctx.clients[0],
+                                     ctx.frozen).shape[0])
             dcs0 = devclust.init_state(
                 max(clusters._capacity_hint, state.n_clients), dim)
         else:
@@ -594,10 +612,11 @@ class StoCFLStrategy(Strategy):
         else:
             dcs0, cap, rows0, has_arr0, obj0, settled0 = \
                 self._cold_carry(ctx, state, clusters)
-        consts = _scan_consts(ctx, dict(_arena_consts(ctx),
-                                        pool=jnp.asarray(pool),
-                                        sizes=_sizes_f32(state),
-                                        init=ctx.init_params))
+        consts = dict(_arena_consts(ctx), pool=jnp.asarray(pool),
+                      sizes=_sizes_f32(state), init=ctx.init_params)
+        if ctx.frozen is not None:
+            consts["frozen"] = ctx.frozen
+        consts = _scan_consts(ctx, consts)
         # carry: everything replicated — the partition/bank rows are
         # cluster-keyed (not client-sharded); the cohort-sharded work is
         # the per-round batches/thetas, whose segment-sums GSPMD lowers
@@ -633,7 +652,8 @@ class StoCFLStrategy(Strategy):
                 # keeps the per-client extractor program identical to
                 # the eager per-client calls — bitwise, not just
                 # allclose); skipped entirely once everyone is observed
-                reps = jax.lax.map(psi, batches)
+                reps = jax.lax.map(lambda b: psi(b, cs.get("frozen")),
+                                   batches)
                 idx = jnp.where(new, ids, cap).astype(jnp.int32)
                 return devclust.DeviceClusterState(
                     parent=d.parent.at[idx].set(
@@ -716,7 +736,8 @@ class StoCFLStrategy(Strategy):
                     rows, cs["init"])
                 thetas = specs.constrain_cohort(thetas, mesh)
             with jax.named_scope("local_update"):
-                thetas_i, omegas_i = cohort(thetas, omega, batches)
+                thetas_i, omegas_i, *stats = cohort(thetas, omega, batches,
+                                                    cs.get("frozen"))
             with jax.named_scope("aggregation"):
                 w = jnp.take(cs["sizes"], ids)
                 omega = AGGREGATORS[aggname](omegas_i, w)
@@ -753,7 +774,8 @@ class StoCFLStrategy(Strategy):
                                    lambda _d: obj, dcs)
             rec = {"n_clusters": n_clusters,
                    "objective": obj,
-                   "sampled": jnp.int32(m)}
+                   "sampled": jnp.int32(m),
+                   **_cohort_stats(stats)}
             return (key, omega, dcs, rows, has, obj, settled), rec
 
         def finalize(state, carry, ys, rounds):
@@ -843,7 +865,7 @@ class StoCFLStrategy(Strategy):
         state, cid = super().join(ctx, state, batch)
         clusters = state.clusters.copy()
         models = state.models
-        rep = ctx.extractor(batch)      # device array; backends convert
+        rep = ctx.extractor(batch, ctx.frozen)  # device array; backends convert
         root, near, _sim = clusters.nearest(rep)
         clusters.observe([cid], [rep])
         if root is not None:
@@ -866,7 +888,7 @@ class StoCFLStrategy(Strategy):
 
     def infer(self, ctx, state, batch):
         """Cluster inference for an unseen client (§4.4), without joining."""
-        rep = ctx.extractor(batch)
+        rep = ctx.extractor(batch, ctx.frozen)
         root, near, sim = state.clusters.nearest(rep)
         src = root if root is not None else near
         model = state.cluster_model(src) if src is not None else state.omega
@@ -883,7 +905,8 @@ class StoCFLStrategy(Strategy):
             return []
         stacked = jax.tree.map(
             lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *batches)
-        reps = np.asarray(jax.vmap(ctx.extractor)(stacked), np.float32)
+        reps = np.asarray(jax.vmap(lambda b: ctx.extractor(b, ctx.frozen))(
+            stacked), np.float32)
         if state.clusters is None or state.clusters.n_clusters() == 0:
             return [{"cluster": None, "seed_from": None, "similarity": 0.0,
                      "model": state.omega} for _ in batches]

@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ref
 from repro.kernels.cosine_sim import cosine_sim as _cosine_pallas
 from repro.kernels.cosine_sim import merge_candidates as _candidates_pallas
+from repro.kernels.grouped_matmul import grouped_matmul as _gmm_pallas
 from repro.kernels.prox_update import prox_update_flat as _prox_pallas
 from repro.kernels.ssm_scan import ssm_scan as _ssm_pallas
 
@@ -117,3 +118,32 @@ def ssm_scan(dA, dBx, C, backend: str = "auto", **kw):
     if backend == "jnp" or (backend == "auto" and not _on_tpu()):
         return ref.ssm_scan_ref(dA, dBx, C)
     return _ssm_pallas(dA, dBx, C, **kw)
+
+
+def _grouped(lhs, rhs, group_sizes, transpose_rhs, backend):
+    if backend == "jnp" or (backend == "auto" and not _on_tpu()):
+        return ref.grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs)
+    return _gmm_pallas(lhs, rhs, group_sizes, transpose_rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def frozen_expert_matmul(lhs, rhs, group_sizes, backend: str = "auto"):
+    """Grouped matmul of expert rows by FROZEN expert weights: (m, k)
+    rows sorted by expert, (G, k, n) weights, (G,) row counts -> (m, n)
+    (``kernels.grouped_matmul``). Its VJP gives the rows' gradient
+    alone, one grouped matmul by the transposed weights; the weights get
+    none (no ``tgmm`` weight-gradient kernel runs), so it is for weights
+    that nothing trains."""
+    return _grouped(lhs, rhs, group_sizes, False, backend)
+
+
+def _frozen_expert_fwd(lhs, rhs, group_sizes, backend):
+    return _grouped(lhs, rhs, group_sizes, False, backend), (rhs, group_sizes)
+
+
+def _frozen_expert_bwd(backend, res, g):
+    rhs, group_sizes = res
+    return _grouped(g, rhs, group_sizes, True, backend), None, None
+
+
+frozen_expert_matmul.defvjp(_frozen_expert_fwd, _frozen_expert_bwd)

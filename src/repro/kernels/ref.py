@@ -55,3 +55,17 @@ def ssm_scan_ref(dA, dBx, C):
     )
     _, ys = jax.lax.scan(step, h0, xs)
     return ys.transpose(1, 0, 2)
+
+
+def grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs=False):
+    """(m, k) rows sorted by group @ their group's (k, n) weight -> (m, n);
+    rows past ``sum(group_sizes)`` read 0. One masked matmul per group."""
+    if transpose_rhs:
+        rhs = jnp.swapaxes(rhs, 1, 2)
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(lhs.shape[0])[:, None]
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), lhs.dtype)
+    for g in range(rhs.shape[0]):
+        mine = (row >= ends[g] - group_sizes[g]) & (row < ends[g])
+        out = out + jnp.where(mine, lhs @ rhs[g], 0.0)
+    return out

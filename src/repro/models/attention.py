@@ -22,7 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import apply_rope, cfg_scan, dense_init
+from repro.models.layers import (apply_rope, cfg_scan, dense, dense_init,
+                                 lora_init, rmsnorm, rmsnorm_init,
+                                 rope_inv_freq, rope_mscale, yarn_mscale)
 from repro.sharding import shard
 
 _CHUNK = 1024          # query-chunk rows for long-sequence attention
@@ -86,18 +88,20 @@ def _attend_rows(q_rows, k, v, mask_rows, scale):
     return jnp.einsum("bhrs,bshd->brhd", probs, v)
 
 
-def causal_attention(q, k, v, cfg, q_offset=0):
+def causal_attention(q, k, v, cfg, q_offset=0, scale=None):
     """Chunked causal (optionally sliding-window) attention.
 
     q: (B,Sq,H,hd); k,v: (B,Sk,H_kv,hd). q_offset = absolute position of
     q[0] relative to k[0] (prefill: 0; not used for decode path).
+    ``scale`` multiplies the logits (default 1/sqrt(hd)).
     """
     B, Sq, H, hd = q.shape
     hd_v = v.shape[-1]                 # MLA: v head dim ≠ qk head dim
     Sk = k.shape[1]
     k = _repeat_kv(k, H)
     v = _repeat_kv(v, H)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     kpos = jnp.arange(Sk)
 
     def mask_for(qpos):
@@ -282,26 +286,62 @@ def mla_init(key, cfg, dtype=jnp.float32):
         "wq": dense_init(ks[0], cfg.d_model, H * (qk_n + qk_r), dtype),
         # kv down-projection to the latent + the shared rope key
         "wkv_a": dense_init(ks[1], cfg.d_model, r + qk_r, dtype),
+        # RMSNorm on the latent before the up-projection (kv_a_layernorm)
+        "kv_a_norm": rmsnorm_init(r, dtype),
         # latent up-projection to per-head k_nope and v
         "wkv_b": dense_init(ks[2], r, H * (qk_n + dv), dtype),
         "wo": dense_init(ks[3], H * dv, cfg.d_model, dtype),
     }
 
 
-def _mla_qkv_full(params, x, cfg, positions):
-    """Expanded (train/prefill) path: materialize per-head K,V."""
+def mla_lora_init(key, cfg, dtype=jnp.float32):
+    """Rank-``cfg.lora_rank`` adapters on MLA's four projections."""
+    H, r, rank = cfg.n_heads, cfg.kv_lora_rank, cfg.lora_rank
+    qk_n, qk_r, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    dims = {"wq": (cfg.d_model, H * (qk_n + qk_r)),
+            "wkv_a": (cfg.d_model, r + qk_r),
+            "wkv_b": (r, H * (qk_n + dv)),
+            "wo": (H * dv, cfg.d_model)}
+    return {n: lora_init(k, *dims[n], rank, dtype)
+            for n, k in zip(dims, jax.random.split(key, len(dims)))}
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times mscale² of YaRN's ``mscale_all_dim``
+    where the config scales its rope (DeepSeek-V2)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, positions, cfg):
+    return apply_rope(x, positions, cfg.rope_theta,
+                      inv_freq=rope_inv_freq(cfg, cfg.qk_rope_dim),
+                      mscale=rope_mscale(cfg))
+
+
+def _lora_scale(cfg) -> float:
+    return cfg.lora_alpha / cfg.lora_rank if cfg.lora_rank else 1.0
+
+
+def _mla_qkv_full(params, x, cfg, positions, lora=None):
+    """Expanded (train/prefill) path: materialize per-head K,V. ``lora``
+    holds adapters of the four projections (or None)."""
     B, S, _ = x.shape
     H, r = cfg.n_heads, cfg.kv_lora_rank
     qk_n, qk_r, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    dt = x.dtype
-    q = (x @ params["wq"].astype(dt)).reshape(B, S, H, qk_n + qk_r)
+    lo, ls = lora or {}, _lora_scale(cfg)
+    q = dense(x, params["wq"], lo.get("wq"), ls).reshape(B, S, H, qk_n + qk_r)
     q_nope, q_rope = q[..., :qk_n], q[..., qk_n:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
 
-    kv_a = x @ params["wkv_a"].astype(dt)                 # (B,S,r+qk_r)
+    kv_a = dense(x, params["wkv_a"], lo.get("wkv_a"), ls)   # (B,S,r+qk_r)
     c_kv, k_rope = kv_a[..., :r], kv_a[..., r:]
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,qk_r)
-    kv = (c_kv @ params["wkv_b"].astype(dt)).reshape(B, S, H, qk_n + dv)
+    c_kv = rmsnorm(params["kv_a_norm"], c_kv)
+    k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)  # (B,S,1,qk_r)
+    kv = dense(c_kv, params["wkv_b"], lo.get("wkv_b"), ls).reshape(
+        B, S, H, qk_n + dv)
     k_nope, v = kv[..., :qk_n], kv[..., qk_n:]
 
     k_rope_b = jnp.broadcast_to(k_rope, (B, S, H, qk_r))
@@ -320,13 +360,15 @@ class _MLACfg:
         self.scan_unroll = cfg.scan_unroll
 
 
-def mla_train(params, x, cfg, positions=None):
+def mla_train(params, x, cfg, positions=None, lora=None):
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    q, k, v, _, _ = _mla_qkv_full(params, x, cfg, positions)
-    out = causal_attention(q, k, v, _MLACfg(cfg))
-    return out.reshape(B, S, -1) @ params["wo"].astype(x.dtype)
+    q, k, v, _, _ = _mla_qkv_full(params, x, cfg, positions, lora)
+    out = causal_attention(q, k, v, _MLACfg(cfg),
+                           scale=mla_softmax_scale(cfg))
+    return dense(out.reshape(B, S, -1), params["wo"],
+                 (lora or {}).get("wo"), _lora_scale(cfg))
 
 
 def mla_prefill(params, x, cfg, positions=None):
@@ -334,7 +376,8 @@ def mla_prefill(params, x, cfg, positions=None):
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     q, k, v, c_kv, k_rope = _mla_qkv_full(params, x, cfg, positions)
-    out = causal_attention(q, k, v, _MLACfg(cfg))
+    out = causal_attention(q, k, v, _MLACfg(cfg),
+                           scale=mla_softmax_scale(cfg))
     out = out.reshape(B, S, -1) @ params["wo"].astype(x.dtype)
     if cfg.sliding_window and S > cfg.sliding_window:
         c_kv = c_kv[:, -cfg.sliding_window:]
@@ -357,12 +400,13 @@ def mla_decode(params, x, cache, pos, cfg):
 
     q = (x @ params["wq"].astype(dt)).reshape(B, 1, H, qk_n + qk_r)
     q_nope, q_rope = q[..., :qk_n], q[..., qk_n:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)[:, 0]   # (B,H,qk_r)
+    q_rope = _mla_rope(q_rope, positions, cfg)[:, 0]               # (B,H,qk_r)
     q_nope = q_nope[:, 0]                                          # (B,H,qk_n)
 
     kv_a = (x @ params["wkv_a"].astype(dt))[:, 0]                  # (B,r+qk_r)
     c_new, kr_new = kv_a[..., :r], kv_a[..., r:]
-    kr_new = apply_rope(kr_new[:, None, None, :], positions, cfg.rope_theta)[:, 0, 0]
+    c_new = rmsnorm(params["kv_a_norm"], c_new)
+    kr_new = _mla_rope(kr_new[:, None, None, :], positions, cfg)[:, 0, 0]
 
     if cfg.sliding_window:
         slot = pos % S_max
@@ -379,7 +423,7 @@ def mla_decode(params, x, cache, pos, cfg):
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk)               # absorbed query
     scores = jnp.einsum("bhr,bsr->bhs", q_lat, c_kv.astype(dt))
     scores = scores + jnp.einsum("bhp,bsp->bhs", q_rope, k_rope.astype(dt))
-    scores = scores.astype(jnp.float32) / jnp.sqrt(float(qk_n + qk_r))
+    scores = scores.astype(jnp.float32) * mla_softmax_scale(cfg)
     mask = jnp.arange(S_max)[None, None, :] < valid_len
     scores = jnp.where(mask, scores, _NEG)
     probs = jax.nn.softmax(scores, axis=-1).astype(dt)

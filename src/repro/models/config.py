@@ -35,11 +35,27 @@ class ModelConfig:
     capacity_factor: float = 1.25
     moe_group_size: int = 4096        # dispatch group tokens (perf knob)
     moe_layer_start: int = 0          # first MoE layer index (deepseek: layer 0 dense)
+    experts_held: int = 0             # routed experts this chip holds (expert
+                                      # parallelism): >0 => the drop-free
+                                      # held-share layer; 0 => all experts,
+                                      # capacity einsum dispatch (GSPMD)
+    norm_topk_prob: bool = True       # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0  # gate scale when not renormalised
     # MLA (deepseek)
     kv_lora_rank: int = 0             # 0 => regular GQA
     qk_rope_dim: int = 64
     qk_nope_dim: int = 128
     v_head_dim: int = 128
+    # YaRN rope scaling (arXiv:2309.00071); yarn_factor 0 => plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # LoRA adapters on the attention projections; rank 0 => none
+    lora_rank: int = 0
+    lora_alpha: float = 0.0
     # SSM (mamba)
     ssm_state: int = 0
     ssm_expand: int = 2

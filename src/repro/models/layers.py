@@ -4,8 +4,11 @@ All modules are (init, apply) pure-function pairs over plain dict pytrees.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.sharding import shard
 
@@ -58,16 +61,82 @@ def rope_freqs(head_dim, theta=10000.0):
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta=10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor, 0.1·mscale·ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's (dim/2,) rotary frequencies (arXiv:2309.00071, as DeepSeek-V2
+    computes them): the interpolated ``base^(-2i/dim) / factor`` below
+    the correction range, the original frequencies above it, and a
+    linear ramp between, the range being where ``beta_fast`` and
+    ``beta_slow`` rotations fit in ``original_max`` positions."""
+    def corr_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    # in float64, rounded to float32 once
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    extra = 1.0 / float(base) ** (np.arange(0, dim, 2) / dim)
+    mask = 1.0 - ramp                      # 1 where the original is kept
+    return (extra / factor * (1.0 - mask) + extra * mask).astype(np.float32)
+
+
+def rope_inv_freq(cfg, dim: int):
+    """The (dim/2,) rotary frequencies of a config: YaRN's where
+    ``cfg.yarn_factor`` is set, plain RoPE at ``cfg.rope_theta`` else."""
+    if cfg.yarn_factor:
+        return jnp.asarray(yarn_inv_freq(
+            dim, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_original_max,
+            cfg.yarn_beta_fast, cfg.yarn_beta_slow))
+    return rope_freqs(dim, cfg.rope_theta)
+
+
+def rope_mscale(cfg) -> float:
+    """The factor on cos and sin under YaRN (1 without it)."""
+    if not cfg.yarn_factor:
+        return 1.0
+    return (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+
+
+def apply_rope(x, positions, theta=10000.0, inv_freq=None, mscale=1.0):
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``inv_freq`` (head_dim/2,) replaces the plain frequencies at
+    ``theta`` (YaRN), ``mscale`` scales cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    freqs = rope_freqs(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]                 # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+# ----------------------------------------------------------------- lora
+def lora_init(key, d_in, d_out, rank, dtype=jnp.float32):
+    """A low-rank adapter (arXiv:2106.09685): A (d_in, r) normal with
+    variance 1/d_in, B (r, d_out) zero, so a fresh adapter adds nothing."""
+    return {"a": dense_init(key, d_in, rank, dtype),
+            "b": jnp.zeros((rank, d_out), dtype)}
+
+
+def dense(x, w, lora=None, scale=1.0):
+    """``x @ w``, plus ``scale · (x @ A) @ B`` for an adapter ``lora``."""
+    dt = x.dtype
+    y = x @ w.astype(dt)
+    if lora is not None:
+        y = y + scale * ((x @ lora["a"].astype(dt)) @ lora["b"].astype(dt))
+    return y
 
 
 # ----------------------------------------------------------------- mlp

@@ -42,11 +42,15 @@ def _layer_init(key, cfg, moe: bool, dtype):
     }
 
 
+def _layer_counts(cfg):
+    n_dense = cfg.moe_layer_start if cfg.n_experts else cfg.n_layers
+    return n_dense, (cfg.n_layers - n_dense if cfg.n_experts else 0)
+
+
 def init(key, cfg):
     dtype = jnp.dtype(cfg.param_dtype)
     ke, kl, kd, kh = jax.random.split(key, 4)
-    n_dense = cfg.moe_layer_start if cfg.n_experts else cfg.n_layers
-    n_moe = cfg.n_layers - n_dense if cfg.n_experts else 0
+    n_dense, n_moe = _layer_counts(cfg)
     params = {
         "embed": embed_init(ke, cfg.vocab_size, cfg.d_model, dtype),
         "final_norm": rmsnorm_init(cfg.d_model, dtype),
@@ -70,7 +74,7 @@ def _layer_train(cfg, moe, h, layer_p):
         h = h + attn.gqa_train(layer_p["attn"], a_in, cfg)
     m_in = rmsnorm(layer_p["mlp_norm"], h)
     if moe:
-        m_out, aux = moe_mod.moe_ffn(layer_p["mlp"], m_in, cfg)
+        m_out, aux = moe_mod.ffn(layer_p["mlp"], m_in, cfg)
     else:
         m_out, aux = swiglu(layer_p["mlp"], m_in), jnp.float32(0.0)
     h = shard(h + m_out, "batch", None, None)
@@ -134,6 +138,87 @@ def lm_loss(params, batch, cfg, forward=forward_train):
     return loss + 0.01 * aux
 
 
+# ------------------------------------------------------------- LoRA fine-tune
+# A frozen backbone (the ``init`` tree, read and never trained) under a
+# trained tree of rank-``cfg.lora_rank`` adapters on MLA's four
+# projections, stacked per layer as the backbone's layers are. The
+# adapters are the params; the backbone is the engine's frozen tree.
+def lora_init(key, cfg):
+    """The trained tree: one set of MLA adapters per layer."""
+    dtype = jnp.dtype(cfg.param_dtype)
+    out = {}
+    for name, n, k in zip(("layers", "moe_layers"), _layer_counts(cfg),
+                          jax.random.split(key)):
+        if n:
+            out[name] = _stack_init(
+                lambda kk: attn.mla_lora_init(kk, cfg, dtype), k, n)
+    return out
+
+
+_NO_STATS = {"moe_rows": 0, "moe_max_expert_rows": 0}
+
+
+def _layer_lora(cfg, moe, h, layer_p, lora_p):
+    with jax.named_scope("mla_attention"):
+        a_in = rmsnorm(layer_p["attn_norm"], h)
+        h = h + attn.mla_train(layer_p["attn"], a_in, cfg, lora=lora_p)
+    m_in = rmsnorm(layer_p["mlp_norm"], h)
+    if moe:
+        m_out, stats = moe_mod.held_moe_ffn(layer_p["mlp"], m_in, cfg)
+    else:
+        with jax.named_scope("dense_ffn"):
+            m_out = swiglu(layer_p["mlp"], m_in)
+        stats = {k: jnp.int32(v) for k, v in _NO_STATS.items()}
+    return h + m_out, stats
+
+
+def lora_forward(adapters, tokens, frozen, cfg):
+    """tokens (B, S) int32 -> (logits (B, S, V), stats), computed in the
+    backbone's dtype; a float32 backbone's matmuls run at float32
+    accuracy (``"high"``: three bf16 passes on a TPU, whose default is
+    one). ``stats`` sums ``moe_rows`` over the expert layers and keeps the
+    largest ``moe_max_expert_rows``."""
+    f32 = frozen["embed"].dtype == jnp.float32
+    with jax.default_matmul_precision("high" if f32 else None):
+        return _lora_forward(adapters, tokens, frozen, cfg)
+
+
+def _lora_forward(adapters, tokens, frozen, cfg):
+    h = jnp.take(frozen["embed"], tokens, axis=0)
+    stats = {k: jnp.int32(v) for k, v in _NO_STATS.items()}
+    for name, moe in (("layers", False), ("moe_layers", True)):
+        if name not in frozen:
+            continue
+        body = functools.partial(_layer_lora, cfg, moe)
+        body = jax.checkpoint(body) if cfg.remat else body
+
+        def step(carry, xs):
+            h, st = carry
+            h, s = body(h, *xs)
+            return (h, {"moe_rows": st["moe_rows"] + s["moe_rows"],
+                        "moe_max_expert_rows": jnp.maximum(
+                            st["moe_max_expert_rows"],
+                            s["moe_max_expert_rows"])}), None
+
+        (h, stats), _ = cfg_scan(cfg, step, (h, stats),
+                                 (frozen[name], adapters[name]))
+    h = rmsnorm(frozen["final_norm"], h)
+    with jax.named_scope("lm_head"):
+        logits = h @ frozen["lm_head"].astype(h.dtype)
+    return logits, stats
+
+
+def lora_loss(adapters, batch, frozen, cfg):
+    """Mean next-token cross-entropy of ``batch["x"]`` against
+    ``batch["y"]`` (each (B, S) int32), and the forward's stats. No
+    routing loss: the router is frozen."""
+    logits, stats = lora_forward(adapters, batch["x"], frozen, cfg)
+    logits = logits.astype(jnp.float32)
+    logz = jax.scipy.special.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, batch["y"][..., None], axis=-1)[..., 0]
+    return jnp.mean(logz - gold), stats
+
+
 # ------------------------------------------------------------- prefill
 def _layer_prefill(cfg, moe, h, layer_p):
     layer_p = unshard_fsdp(layer_p)
@@ -145,7 +230,7 @@ def _layer_prefill(cfg, moe, h, layer_p):
     h = h + a_out
     m_in = rmsnorm(layer_p["mlp_norm"], h)
     if moe:
-        m_out, _ = moe_mod.moe_ffn(layer_p["mlp"], m_in, cfg)
+        m_out, _ = moe_mod.ffn(layer_p["mlp"], m_in, cfg)
     else:
         m_out = swiglu(layer_p["mlp"], m_in)
     return shard(h + m_out, "batch", None, None), cache
@@ -185,7 +270,7 @@ def _layer_decode(cfg, moe, carry, inp):
     h = h + a_out
     m_in = rmsnorm(layer_p["mlp_norm"], h)
     if moe:
-        m_out, _ = moe_mod.moe_ffn(layer_p["mlp"], m_in, cfg)
+        m_out, _ = moe_mod.ffn(layer_p["mlp"], m_in, cfg)
     else:
         m_out = swiglu(layer_p["mlp"], m_in)
     return (h + m_out, pos), new_cache

@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.cosine_sim import cosine_sim, merge_candidates
+from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.prox_update import prox_update_flat
 
 D_MLP = 19210      # |θ| of SYNTH_MLP 64→256→10, the Ψ width of the MLP task
@@ -69,3 +70,20 @@ def test_prox_update_compiles_under_cohort_vmap_for_v5e(one_chip, dtype):
     step = jax.vmap(lambda a, b, c, e: prox_update_flat(
         a, b, c, e, 0.1, 0.05, donate=False))
     assert "tpu_custom_call" in _compiled_text(step, v, v, v, v)
+
+
+# DeepSeek-V2-Lite's expert FFN on one chip: 8 held experts of width 1408
+# at d 2048, the row capacity of one client batch (16 x 512 tokens, top 6)
+@pytest.mark.parametrize("k,n,transpose", [(2048, 1408, False),
+                                           (1408, 2048, False),
+                                           (1408, 2048, True),
+                                           (2048, 1408, True)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, k, n, transpose):
+    rows = jax.ShapeDtypeStruct((16 * 512 * 6, k), jnp.float32,
+                                sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, n, k) if transpose else (8, k, n),
+                             jnp.float32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(lambda a, b, c: grouped_matmul(a, b, c, transpose),
+                          rows, w, sizes)
+    assert "tpu_custom_call" in text
