@@ -97,16 +97,16 @@ def grow(state: DeviceClusterState, capacity: int) -> DeviceClusterState:
 
 
 # ----------------------------------------------------------- jitted math
-def _cluster_means(state: DeviceClusterState):
+def _cluster_means(state: DeviceClusterState, mesh=None):
     """(root, means, counts): per-row resolved root (dead rows → the
     scratch segment ``cap``), per-root-row member-mean Ψ̃ and member
-    count (zero for non-root rows)."""
+    count (zero for non-root rows). The member sums are one row segment
+    sum over the Ψ bank (``kernels.ops.segment_rows``); ``mesh`` is the
+    client mesh a calling program is partitioned over."""
     cap = state.parent.shape[0]
     root = ops.resolve_roots(state.parent)
     seg = jnp.where(state.live, root, cap)
-    sums = jax.ops.segment_sum(
-        jnp.where(state.live[:, None], state.rep, 0.0), seg,
-        num_segments=cap + 1)[:cap]
+    sums = ops.segment_rows(state.rep, seg, state.live, mesh=mesh)
     counts = jax.ops.segment_sum(
         state.live.astype(jnp.float32), seg, num_segments=cap + 1)[:cap]
     means = sums / jnp.maximum(counts, 1.0)[:, None]
@@ -146,11 +146,28 @@ def component_labels(adj, steps: Optional[int] = None):
         (jnp.full((n,), -1, jnp.int32), ids))[1]
 
 
+def state_mesh(state: DeviceClusterState):
+    """The mesh a state's Ψ bank lies on, or None on one device.
+
+    A ``run_rounds`` span on a client mesh hands back its carry's bank
+    replicated over every chip of the mesh. A jitted program that takes
+    such a state runs the Pallas kernels of ``_cluster_means`` and
+    ``merge_pairs`` per chip under ``shard_map`` over this mesh: GSPMD
+    cannot partition a Mosaic kernel."""
+    sharding = state.rep.sharding
+    if len(sharding.device_set) < 2:
+        return None
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        return sharding.mesh
+    devices = sorted(sharding.device_set, key=lambda d: d.id)
+    return jax.sharding.Mesh(np.array(devices), ("clients",))
+
+
 @functools.lru_cache(maxsize=None)
-def _jit_cluster_means():
+def _jit_cluster_means(mesh=None):
     """Jitted ``_cluster_means`` (memoized wrapper, one compile per
-    capacity)."""
-    return jax.jit(_cluster_means)
+    capacity and mesh)."""
+    return jax.jit(functools.partial(_cluster_means, mesh=mesh))
 
 
 @functools.lru_cache(maxsize=None)
@@ -198,7 +215,7 @@ def merge_round_impl(state: DeviceClusterState, tau: float, k_max: int,
     parents."""
     cap = state.parent.shape[0]
     ids = jnp.arange(cap, dtype=jnp.int32)
-    root, means, counts = _cluster_means(state)
+    root, means, counts = _cluster_means(state, mesh)
     # live-root rows, ascending (so compact row order = root-id
     # order and a min row index IS the min root id); pads → cap
     (rows,) = jnp.nonzero(counts > 0, size=k_max, fill_value=cap)
@@ -228,9 +245,10 @@ def merge_round_impl(state: DeviceClusterState, tau: float, k_max: int,
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_merge_round(tau: float, k_max: int):
-    """Jitted ``merge_round_impl`` (one compile per (τ, k_max))."""
-    return jax.jit(functools.partial(merge_round_impl, tau=tau, k_max=k_max))
+def _jit_merge_round(tau: float, k_max: int, mesh=None):
+    """Jitted ``merge_round_impl`` (one compile per (τ, k_max, mesh))."""
+    return jax.jit(functools.partial(merge_round_impl, tau=tau, k_max=k_max,
+                                     mesh=mesh))
 
 
 @functools.lru_cache(maxsize=None)
@@ -272,12 +290,13 @@ def _jit_remove():
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_nearest():
+def _jit_nearest(mesh=None):
     """(state, query) -> (best root, best cosine, live-cluster count):
-    §4.4 nearest-cluster-by-Ψ, dead rows masked to −inf."""
+    §4.4 nearest-cluster-by-Ψ, dead rows masked to −inf. ``mesh``: the
+    state's (``state_mesh``)."""
 
     def run(state, query):
-        _, means, counts = _cluster_means(state)
+        _, means, counts = _cluster_means(state, mesh)
         qn = query / (jnp.linalg.norm(query) + 1e-12)
         mn = means / (jnp.linalg.norm(means, axis=1, keepdims=True) + 1e-12)
         sims = jnp.where(counts > 0, mn @ qn, -jnp.inf)
@@ -287,15 +306,15 @@ def _jit_nearest():
     return jax.jit(run)
 
 
-def objective_impl(state: DeviceClusterState, k_max: int):
+def objective_impl(state: DeviceClusterState, k_max: int, mesh=None):
     """Traceable Eq. 2 objective Σ_{i<j} cos(Ψ̃_i, Ψ̃_j) over live
     clusters (0 with fewer than two). ``k_max`` (static live-cluster
     bound) compacts the pairwise work to O(k_max²), same as the merge
     pass — a settled big-capacity federation pays a K̃′² matrix, not a
     capacity² one. The ``run_rounds`` scan inlines this with
-    ``k_max = capacity``."""
+    ``k_max = capacity``. ``mesh``: as in ``merge_round_impl``."""
     cap = state.parent.shape[0]
-    _, means, counts = _cluster_means(state)
+    _, means, counts = _cluster_means(state, mesh)
     (rows,) = jnp.nonzero(counts > 0, size=k_max, fill_value=cap)
     means_ext = jnp.concatenate(
         [means, jnp.zeros((1, means.shape[1]), means.dtype)])
@@ -311,12 +330,12 @@ def objective_impl(state: DeviceClusterState, k_max: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_objective(k_max: int):
-    """Jitted ``objective_impl`` (one compile per k_max)."""
-    return jax.jit(functools.partial(objective_impl, k_max=k_max))
+def _jit_objective(k_max: int, mesh=None):
+    """Jitted ``objective_impl`` (one compile per (k_max, mesh))."""
+    return jax.jit(functools.partial(objective_impl, k_max=k_max, mesh=mesh))
 
 
-def objective_closed_impl(state: DeviceClusterState):
+def objective_closed_impl(state: DeviceClusterState, mesh=None):
     """Eq. 2 as the closed form ``(‖Σ m̂‖² − Σ ‖m̂‖²)/2`` over the live
     clusters' normalized means — O(capacity·D), no pairwise matrix and
     no live-cluster compaction, so the reduction SHAPE depends only on
@@ -325,8 +344,9 @@ def objective_closed_impl(state: DeviceClusterState):
     the eager loop and the ``run_rounds`` scan then record bitwise-equal
     trajectories, while the cost stays linear in capacity instead of
     the pairwise k_max². (Same quantity as ``objective_impl`` up to
-    float association; exact 0.0 with fewer than two clusters.)"""
-    _, means, counts = _cluster_means(state)
+    float association; exact 0.0 with fewer than two clusters.)
+    ``mesh``: as in ``merge_round_impl``."""
+    _, means, counts = _cluster_means(state, mesh)
     norms = jnp.linalg.norm(means, axis=1, keepdims=True)
     mn = jnp.where((counts[:, None] > 0) & (norms > 0), means / norms, 0.0)
     s = jnp.sum(mn, axis=0)
@@ -334,15 +354,16 @@ def objective_closed_impl(state: DeviceClusterState):
 
 
 @functools.lru_cache(maxsize=None)
-def _jit_objective_closed():
-    """Jitted ``objective_closed_impl`` (one compile per capacity)."""
-    return jax.jit(objective_closed_impl)
+def _jit_objective_closed(mesh=None):
+    """Jitted ``objective_closed_impl`` (one compile per capacity and
+    mesh)."""
+    return jax.jit(functools.partial(objective_closed_impl, mesh=mesh))
 
 
 def objective_closed(state: DeviceClusterState) -> float:
     """Host wrapper for ``objective_closed_impl`` (the engine's eager
     device-backend metric call)."""
-    return float(_jit_objective_closed()(state))
+    return float(_jit_objective_closed(state_mesh(state))(state))
 
 
 # public jitted-transition aliases (the DeviceClusterState-level API)
@@ -363,12 +384,12 @@ def merge_round(state: DeviceClusterState, tau: float,
     safe). Callers that track K̃ pass its pow2 quantization."""
     cap = int(state.parent.shape[0])
     k_max = cap if k_max is None else min(_pow2(k_max), cap)
-    return _jit_merge_round(float(tau), k_max)(state)
+    return _jit_merge_round(float(tau), k_max, state_mesh(state))(state)
 
 
 def nearest(state: DeviceClusterState, query):
     """(best root row, best cosine, live-cluster count) for a Ψ query."""
-    return _jit_nearest()(state, query)
+    return _jit_nearest(state_mesh(state))(state, query)
 
 
 def infer(state: DeviceClusterState, query, tau: float):
@@ -562,7 +583,8 @@ class DeviceClusters:
         """(sorted roots, (K̃, D) member-mean matrix) — host pull of the
         device segment means, numpy-API-shaped for tests/tools."""
         roots = sorted({int(self._parent[c]) for c in self.seen})
-        _, means, _ = _jit_cluster_means()(self._state)
+        _, means, _ = _jit_cluster_means(state_mesh(self._state))(
+            self._state)
         return roots, np.asarray(means)[np.asarray(roots, np.int64)]
 
     def similarity_matrix(self) -> Tuple[List[int], np.ndarray]:
@@ -619,7 +641,8 @@ class DeviceClusters:
         if k < 2:
             return 0.0
         k_max = min(_pow2(k), self.capacity)
-        return float(_jit_objective(k_max)(self._state))
+        return float(_jit_objective(k_max, state_mesh(self._state))(
+            self._state))
 
     # ----------------------------------------------------------- departure
     def remove(self, cid: int) -> Dict[int, int]:

@@ -562,7 +562,8 @@ class StoCFLStrategy(Strategy):
         # metric path calls (objective_closed), so a cache-carried value
         # is the exact float eager would have recorded for an unchanged
         # partition
-        obj0 = devclust._jit_objective_closed()(dcs0).astype(jnp.float32)
+        obj0 = devclust._jit_objective_closed(devclust.state_mesh(dcs0))(
+            dcs0).astype(jnp.float32)
         return (dcs0, cap, rows0, jnp.asarray(has0), obj0,
                 jnp.asarray(False))
 
@@ -770,7 +771,8 @@ class StoCFLStrategy(Strategy):
                 # reduction), so the O(capacity·D) recompute is
                 # cond-skipped
                 obj = jax.lax.cond(new_any | any_merged,
-                                   devclust.objective_closed_impl,
+                                   lambda d: devclust.objective_closed_impl(
+                                       d, mesh),
                                    lambda _d: obj, dcs)
             rec = {"n_clusters": n_clusters,
                    "objective": obj,

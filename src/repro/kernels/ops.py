@@ -6,6 +6,13 @@ forces the oracle. Nothing here runs a kernel in interpret mode or
 falls back to the oracle after a TPU compile error: the kernel tests
 call the kernels in interpret mode themselves, and
 ``tests/test_tpu_compile.py`` compiles them for a described v5e.
+
+Wrappers, by kernel module: ``pairwise_cosine`` and ``merge_pairs``
+(``cosine_sim``); ``segment_rows`` (``segment_rows``, the cluster
+means' row segment sum; the oracle on TPU too above the K its VMEM
+blocks hold); ``prox_update_tree`` and ``prox_update_flat``
+(``prox_update``); ``ssm_scan``; ``frozen_expert_matmul``
+(``grouped_matmul``); and ``resolve_roots``, XLA on every platform.
 """
 from __future__ import annotations
 
@@ -20,6 +27,7 @@ from repro.kernels.cosine_sim import cosine_sim as _cosine_pallas
 from repro.kernels.cosine_sim import merge_candidates as _candidates_pallas
 from repro.kernels.grouped_matmul import grouped_matmul as _gmm_pallas
 from repro.kernels.prox_update import prox_update_flat as _prox_pallas
+from repro.kernels import segment_rows as _segment_rows
 from repro.kernels.ssm_scan import ssm_scan as _ssm_pallas
 
 
@@ -49,6 +57,28 @@ def merge_pairs(means, live, tau: float, backend: str = "auto", mesh=None):
         kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(P(), P()),
                                out_specs=P(), check_vma=False)
     return kernel(means, live)
+
+
+def segment_rows(x, seg, live, mesh=None):
+    """(K, D) rows + (K,) segment ids + (K,) live mask -> (K, D) f32
+    row sums: live row i adds into output row ``seg[i]``; dead rows, and
+    rows whose ``seg[i]`` lies outside [0, K) (the scratch segment K),
+    add into nothing. The numerator of the cluster means.
+
+    On TPU the Pallas kernel ``segment_rows.segment_row_sum`` (rows
+    added in ascending i, plain f32 adds, the bank read once and never
+    copied) over ``seg`` with dead rows sent to K, unless K is too large
+    for its (K, 128) VMEM blocks (``segment_rows.fits``); elsewhere the
+    oracle, XLA's scatter-add over the live-masked rows
+    (``ref.segment_rows_ref``). ``mesh``: as in ``merge_pairs``."""
+    k = x.shape[0]
+    if not (_on_tpu() and _segment_rows.fits(k)):
+        return ref.segment_rows_ref(x, seg, live)
+    kernel = _segment_rows.segment_row_sum
+    if mesh is not None:
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=P(), check_vma=False)
+    return kernel(x, jnp.where(live, seg, k))
 
 
 def resolve_roots(parent):

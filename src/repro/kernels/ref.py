@@ -23,6 +23,14 @@ def merge_candidates_ref(x, live, tau):
     return ok.astype(jnp.float32)
 
 
+def segment_rows_ref(x, seg, live):
+    """(K, D) rows, (K,) segment ids (dead rows: K), (K,) live -> (K, D)
+    row sums by XLA's scatter-add over the live-masked rows."""
+    k = x.shape[0]
+    return jax.ops.segment_sum(jnp.where(live[:, None], x, 0.0), seg,
+                               num_segments=k + 1)[:k]
+
+
 def resolve_roots_ref(parent):
     """(N,) union-find parent pointers -> (N,) roots by iterated pointer
     halving ``p <- p[p]`` (⌈log2 N⌉+1 steps: each halves every path)."""
